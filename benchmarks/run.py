@@ -103,6 +103,9 @@ def main(argv=None) -> None:
 if __name__ == "__main__":
     # `python benchmarks/run.py` puts benchmarks/ (not the repo root) on
     # sys.path; add the root so `benchmarks.<mod>` imports resolve.
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path[:0] = [root, os.path.join(root, "src")]
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache(root)
     main()

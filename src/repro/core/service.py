@@ -62,7 +62,12 @@ class ParameterService:
     loss_limit: float = 0.1
     strict_paper: bool = False
     preserve_spread: bool = False
-    plan_pad_to: int = 128  # shard padding granularity of compiled plans
+    # Shard padding granularity of compiled plans: every plan's
+    # block_align, hence the tile of the tick and relayout kernels.  On
+    # the TPU it must be a multiple of 1024 (repro.kernels.tiling), and at
+    # 16384 no tick that fits a v5e's HBM overflows the kernels' SMEM
+    # block tables.
+    plan_pad_to: int = 16384
     # Replan-transaction retry schedule; None -> RetryPolicy() defaults
     # (2 retries, no sleeping).  Shared type with the engines' apply
     # retries (repro.ps.faults.RetryPolicy).

@@ -19,9 +19,10 @@ how much co-resident state shares the space.
 pending updates run as ONE launch.  Two scalar-prefetched operands drive
 the grid -- a concatenated owned-block index table (all participating
 jobs' blocks back to back) and a per-block job-slot map -- so grid step i
-DMAs block ``block_idx[i]`` of the shared buffers and row ``job_slot[i]``
-of a (K, HP_COLS) per-job hyperparameter table (lr, betas and their
-pre-folded complements, eps, bias-correction reciprocals, weight decay).
+DMAs block ``block_idx[i]`` of the shared buffers and reads row
+``job_slot[i]`` of a (K, HP_COLS) per-job hyperparameter table held whole
+in SMEM (lr, betas and their pre-folded complements, eps, bias-correction
+reciprocals, weight decay).
 Block exclusivity (every block belongs to at
 most one job) is what makes the batched pass semantically identical to K
 sequential per-job updates.
@@ -34,6 +35,12 @@ scatters disappear and a whole service tick is ONE kernel launch.
 
 VMEM budget at BLOCK=16384 fp32: (W + 5) x 64 KiB tiles -- e.g. W=8 -> 832
 KiB, comfortably inside the ~16 MiB v5e VMEM with double buffering.
+
+SMEM budget of the multi-job forms: the two int32 prefetch tables take
+8 B per grid step, and v5e SMEM holds 1 MiB.  At BLOCK=16384 the largest
+launch 16 GB of HBM can hold (~1.3e9 fp32 elements of p/mu/nu, ~82k
+blocks) needs ~650 KiB; at 2048 a 348M-element tick already overflows.
+On the TPU every block must be a multiple of ``tiling.TPU_TILE``.
 """
 
 from __future__ import annotations
@@ -45,7 +52,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 16384  # elements per tile; 128-aligned for VPU lanes
+from repro.kernels.tiling import check_block
+
+BLOCK = 16384  # elements per tile; a multiple of tiling.TPU_TILE
 
 
 def _kernel(p_ref, g_ref, mu_ref, nu_ref, bc_ref, out_p, out_mu, out_nu,
@@ -80,6 +89,8 @@ def aggregate_adam(p, grads, mu, nu, count, *, lr, b1=0.9, b2=0.999,
     N must be a multiple of `block` (ops.py pads)."""
     n = p.shape[-1]
     assert n % block == 0, f"N={n} not a multiple of block={block}"
+    if not interpret:
+        check_block(block)
     grid = (n // block,)
     t = count.astype(jnp.float32)
     bc = jnp.stack([1.0 / (1.0 - b1 ** t), 1.0 / (1.0 - b2 ** t)])
@@ -142,6 +153,8 @@ def aggregate_adam_blocks(p, grads, mu, nu, count, block_idx, *, lr, b1=0.9,
     """
     n = mu.shape[-1]
     assert n % block == 0, f"N={n} not a multiple of block={block}"
+    if not interpret:
+        check_block(block)
     n_own = block_idx.shape[0]
     m = grads.shape[-1]
     assert m == n_own * block, (
@@ -184,17 +197,19 @@ HP_COLS = 16  # (lr, b1, 1-b1, b2, 1-b2, eps, bc1, bc2, wd, pad...) per job
 
 def _multijob_kernel(bidx_ref, jslot_ref, p_ref, g_ref, mu_ref, nu_ref,
                      hp_ref, out_p, out_mu, out_nu):
-    # bidx/jslot are consumed by the BlockSpec index maps; the hyperparams
-    # arrive as this block's owner-job row of the (K, HP_COLS) table.
+    # bidx is consumed by the BlockSpec index maps; the whole (K, HP_COLS)
+    # hyperparameter table sits in SMEM and this block's owner row is
+    # picked by the prefetched job slot.
     # Same arithmetic form as _kernel, with the compile-time constants
-    # replaced by the prefetched per-job scalars; 1-b1 / 1-b2 come
-    # PRE-FOLDED from the table because the dense kernels fold them from
-    # python doubles at trace time -- recomputing them here in f32
+    # replaced by the per-job scalars; 1-b1 / 1-b2 come PRE-FOLDED from
+    # the table because the dense kernels fold them from python doubles
+    # at trace time -- recomputing them here in f32
     # (1.0 - 0.9f != f32(1.0 - 0.9)) would break bit-parity.
-    del bidx_ref, jslot_ref
-    lr, b1, omb1 = hp_ref[0, 0], hp_ref[0, 1], hp_ref[0, 2]
-    b2, omb2, eps = hp_ref[0, 3], hp_ref[0, 4], hp_ref[0, 5]
-    bc1, bc2, wd = hp_ref[0, 6], hp_ref[0, 7], hp_ref[0, 8]
+    del bidx_ref
+    j = jslot_ref[pl.program_id(0)]
+    lr, b1, omb1 = hp_ref[j, 0], hp_ref[j, 1], hp_ref[j, 2]
+    b2, omb2, eps = hp_ref[j, 3], hp_ref[j, 4], hp_ref[j, 5]
+    bc1, bc2, wd = hp_ref[j, 6], hp_ref[j, 7], hp_ref[j, 8]
     g = g_ref[...].astype(jnp.float32)
     if g.ndim == 2:  # (W, BLOCK) worker pushes -> sum-aggregate
         g = g.sum(axis=0)
@@ -231,6 +246,8 @@ def aggregate_adam_multijob_fused(p, grads, mu, nu, hp, block_idx, job_slot,
     """
     n = mu.shape[-1]
     assert n % block == 0, f"N={n} not a multiple of block={block}"
+    if not interpret:
+        check_block(block)
     n_own = block_idx.shape[0]
     assert job_slot.shape == (n_own,), (job_slot.shape, n_own)
     m = grads.shape[-1]
@@ -248,7 +265,7 @@ def aggregate_adam_multijob_fused(p, grads, mu, nu, hp, block_idx, job_slot,
                               lambda i, bidx, jslot: (0, i))
     else:
         g_spec = pl.BlockSpec((block,), lambda i, bidx, jslot: (i,))
-    hp_spec = pl.BlockSpec((1, HP_COLS), lambda i, bidx, jslot: (jslot[i], 0))
+    hp_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_own,),
@@ -290,12 +307,15 @@ def aggregate_adam_multijob(p, grads, mu, nu, hp, block_idx, job_slot, *,
     owned-block table; job_slot: (n_own,) int32 row of ``hp`` owning each
     block.
 
-    Grid step i DMAs tile ``block_idx[i]`` of the shared buffers, tile i of
-    the packed operands, and row ``job_slot[i]`` of hp, then writes tile i
-    of the PACKED outputs.  Returns (new_p, new_mu, new_nu), each (M,).
+    Grid step i DMAs tile ``block_idx[i]`` of the shared buffers and tile i
+    of the packed operands, reads row ``job_slot[i]`` of the SMEM-resident
+    hp, then writes tile i of the PACKED outputs.  Returns (new_p, new_mu,
+    new_nu), each (M,).
     """
     n = mu.shape[-1]
     assert n % block == 0, f"N={n} not a multiple of block={block}"
+    if not interpret:
+        check_block(block)
     n_own = block_idx.shape[0]
     assert job_slot.shape == (n_own,), (job_slot.shape, n_own)
     m = grads.shape[-1]
@@ -314,7 +334,7 @@ def aggregate_adam_multijob(p, grads, mu, nu, hp, block_idx, job_slot, *,
     else:
         g_spec = packed
     p_spec = packed if p_packed else owned
-    hp_spec = pl.BlockSpec((1, HP_COLS), lambda i, bidx, jslot: (jslot[i], 0))
+    hp_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_own,),

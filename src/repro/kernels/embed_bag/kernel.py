@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(idx_ref, row_ref, out_ref):
@@ -37,7 +38,8 @@ def embedding_bag(table, indices, *, interpret=False):
     b, l = indices.shape
     flat_idx = indices.reshape(-1)
 
-    grid_spec = pl.GridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, l),
         in_specs=[
             # one table row per step, selected by the prefetched indices
@@ -45,19 +47,6 @@ def embedding_bag(table, indices, *, interpret=False):
         ],
         out_specs=pl.BlockSpec((1, d), lambda i, j, idx: (i, 0)),
     )
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, l),
-            in_specs=[
-                pl.BlockSpec((1, d), lambda i, j, idx: (idx[i * l + j], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, d), lambda i, j, idx: (i, 0)),
-        )
-    except ImportError:  # pragma: no cover
-        pass
 
     return pl.pallas_call(
         _kernel,

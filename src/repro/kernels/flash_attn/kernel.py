@@ -38,8 +38,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, bk, seq_k):
 
     def body(j, carry):
         acc, m, l = carry
-        k_tile = pl.load(k_ref, (pl.dslice(j * bk, bk), slice(None)))
-        v_tile = pl.load(v_ref, (pl.dslice(j * bk, bk), slice(None)))
+        k_tile = k_ref[pl.ds(j * bk, bk), :]
+        v_tile = v_ref[pl.ds(j * bk, bk), :]
         s = q @ k_tile.astype(jnp.float32).T  # (BQ, BK)
         if causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)

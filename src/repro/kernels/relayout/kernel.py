@@ -23,7 +23,8 @@ a run may move a block onto another run's source without ordering
 constraints on the grid.
 
 VMEM budget: 2 x n_leaves tiles of ``block`` fp32 lanes -- at the
-shipped block_align (128..16384) this is KBs, far inside v5e VMEM.
+service's block_align (16384) this is 128 KiB per leaf, far inside v5e
+VMEM.  On the TPU ``block`` must be a multiple of ``tiling.TPU_TILE``.
 """
 
 from __future__ import annotations
@@ -32,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import check_block
 
 
 def _kernel(dst_ref, *refs):
@@ -65,6 +68,8 @@ def relayout_scatter(bases, staged, dst_blocks, *, block, interpret=False):
     n_t = int(dst_blocks.shape[0])
     n = bases[0].shape[-1]
     assert n % block == 0, f"N={n} not a multiple of block={block}"
+    if not interpret:
+        check_block(block)
     for b, s in zip(bases, staged):
         assert b.shape == (n,), (b.shape, n)
         assert s.shape == (n_t * block,), (s.shape, n_t, block)

@@ -140,8 +140,9 @@ def relayout(leaves: Sequence, delta, *,
         # content moves at all.
         return [_resize(x, delta.old_len, delta.new_len) for x in leaves]
 
-    use_kernel = (_on_tpu() if interpret is None else not interpret)
-    if use_kernel and delta.new_len % delta.block == 0:
+    if (_on_tpu() if interpret is None else not interpret):
+        # The kernel is the only TPU path: a layout it cannot take raises
+        # (relayout_scatter) instead of falling back to the jnp programs.
         bases = [_resize(x, delta.old_len, delta.new_len) for x in leaves]
         staged = [_stage(x, delta) for x in leaves]
         return list(K.relayout_scatter(

@@ -5,10 +5,6 @@ touches jax device state. Single pod: 16 x 16 = 256 chips (data, model).
 Multi-pod: 2 x 16 x 16 = 512 chips (pod, data, model); the "pod" axis is an
 extra data-parallel dimension whose collectives cross the inter-pod (DCN)
 links -- the dry-run proves the HLO shards across it.
-
-`make_mesh` / `make_abstract_mesh` paper over the jax API drift around
-axis types (jax.sharding.AxisType only exists on newer jax; older
-AbstractMesh takes (name, size) pairs).
 """
 
 from __future__ import annotations
@@ -19,25 +15,19 @@ import jax
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Version-compatible jax.make_mesh with Auto axis types when supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(axis_type.Auto,) * len(axes),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Version-compatible AbstractMesh (rule logic only needs .shape)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.sharding.AbstractMesh(
-            tuple(shape), tuple(axes),
-            axis_types=(axis_type.Auto,) * len(axes),
-        )
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """AbstractMesh with Auto axis types (rule logic only needs .shape)."""
+    return jax.sharding.AbstractMesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

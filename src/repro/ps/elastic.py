@@ -562,7 +562,11 @@ def migrate_sharded_state(
     old_by = old.by_skey
     for sid, sp in zip(new.shard_ids, new.shards):
         prev = states.get(sid) if sid in old_ids else None
-        if prev is not None:
+        if prev is not None and old.shard_of(sid) == sp:
+            # Unchanged shard space: nothing moves, and compiling its
+            # delta would still cost O(shard lanes) on the host.
+            st = dict(prev)
+        elif prev is not None:
             old_sp = old.shard_of(sid)
             delta = compile_migration_delta(old_sp, sp)
             st = migrate_flat_state_delta(

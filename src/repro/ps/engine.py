@@ -375,6 +375,35 @@ def _copy_state(state):
         lambda x: x.copy() if hasattr(x, "copy") else x, state)
 
 
+class _Applier:
+    """One applier program, compiled apart from its execution.
+
+    :meth:`compiled` lowers and compiles the jitted (state-donating)
+    program for the given arguments' types -- once per signature -- and
+    returns what to call; the ticks call it OUTSIDE their failure
+    handling, so a lowering or compile error (a kernel the chip cannot
+    take) propagates to the caller instead of being rolled back, retried
+    and quarantined as if an apply had failed.  Eager (``jit=False``)
+    programs have no compile step and run as they are."""
+
+    __slots__ = ("_fn", "_jit", "_exes")
+
+    def __init__(self, fn: Callable, jit: bool):
+        self._fn = jax.jit(fn, donate_argnums=(0,)) if jit else fn
+        self._jit = jit
+        self._exes: Dict[Any, Callable] = {}
+
+    def compiled(self, *args) -> Callable:
+        if not self._jit:
+            return self._fn
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        sig = (tree, tuple(jax.typeof(x) for x in leaves))
+        exe = self._exes.get(sig)
+        if exe is None:
+            exe = self._exes[sig] = self._fn.lower(*args).compile()
+        return exe
+
+
 # ------------------------------------------------ shared applier building
 def _flat_job_hp(info) -> Tuple[float, float, float, float]:
     """(lr, b1, b2, eps) of one flat-runtime job (Adam knobs ride in
@@ -927,17 +956,19 @@ class ServiceTickEngine:
                         self._appliers.pop(next(iter(self._appliers)))
                     self._appliers[key] = applier
                 gs = tuple(packed for packed, _, _ in heads)
+                run = applier.compiled(self.runtime.state, gs)
             except BaseException:
-                # Build-time failure (e.g. a non-block-exclusive layout):
-                # no device op ran, so re-queue the popped heads --
-                # nothing is lost and a later tick can retry.
+                # Build- or compile-time failure (e.g. a
+                # non-block-exclusive layout, a kernel the backend cannot
+                # lower): no device op ran, so re-queue the popped heads
+                # -- nothing is lost and a later tick can retry.
                 for j, head in zip(key, heads):
                     self._queues[j].appendleft(head)
                 raise
             try:
                 if self.fault_injector is not None:
                     self.fault_injector.on_apply(None)
-                self.runtime.state = applier(self.runtime.state, gs)
+                self.runtime.state = run(self.runtime.state, gs)
             except BaseException as exc:
                 # Execution failure: the jitted applier DONATES the state
                 # buffers, so they may already be deleted.  Re-queue the
@@ -1106,7 +1137,7 @@ class ServiceTickEngine:
             return new_state
 
         # Donate the shared state: flat/mu/nu update in place per tick.
-        return jax.jit(apply, donate_argnums=(0,)) if self._jit else apply
+        return _Applier(apply, self._jit)
 
 
 # --------------------------------------------------------------- sharded
@@ -1631,16 +1662,18 @@ class ShardedTickEngine:
                     lane.appliers[key] = applier
                 gs = tuple(piece for piece, _, _, _ in heads)
                 counts = tuple(count for _, count, _, _ in heads)
+                run = applier.compiled(self.runtime.states[shard_id], gs,
+                                       counts)
             except BaseException:
-                # Build-time failure: no device op ran; re-queue and let a
-                # later tick retry.
+                # Build- or compile-time failure: no device op ran;
+                # re-queue and let a later tick retry.
                 for j, head in zip(key, heads):
                     lane.queues[j].appendleft(head)
                 raise
             try:
                 if self.fault_injector is not None:
                     self.fault_injector.on_apply(shard_id)
-                self.runtime.states[shard_id] = applier(
+                self.runtime.states[shard_id] = run(
                     self.runtime.states[shard_id], gs, counts)
             except BaseException as exc:
                 # Execution failure: the jitted applier DONATED this
@@ -1792,14 +1825,21 @@ class ShardedTickEngine:
         if not entries:
             return 0
         key = tuple(entries)
-        # Build BEFORE popping: a build failure (e.g. mixed block_align
-        # across lanes) leaves every queue untouched for a later retry.
+        # Build and compile BEFORE popping: a build failure (e.g. mixed
+        # block_align across lanes) or a compile failure leaves every
+        # queue untouched and propagates -- neither is an apply failure.
         applier = self._fleet_appliers.get(key)
         if applier is None:
             applier = self._build_fleet_applier(key)
             if len(self._fleet_appliers) >= self.MAX_APPLIERS:
                 self._fleet_appliers.pop(next(iter(self._fleet_appliers)))
             self._fleet_appliers[key] = applier
+        heads = [self._lanes[sid].queues[j][0]
+                 for sid, jobs in key for j in jobs]
+        gs = tuple(head[0] for head in heads)
+        counts = tuple(head[1] for head in heads)
+        states = tuple(self.runtime.states[sid] for sid, _ in key)
+        run = applier.compiled(states, gs, counts)
         # Snapshot every participating lane BEFORE popping: queues are
         # intact, so each lane's (snapshot, empty log) anchors a rollback
         # of this very launch.
@@ -1812,14 +1852,11 @@ class ShardedTickEngine:
             lane = self._lanes[sid]
             for j in jobs:
                 popped.append((sid, j, lane.queues[j].popleft()))
-        gs = tuple(head[0] for _, _, head in popped)
-        counts = tuple(head[1] for _, _, head in popped)
-        states = tuple(self.runtime.states[sid] for sid, _ in key)
         try:
             if self.fault_injector is not None:
                 for sid, _ in key:
                     self.fault_injector.on_apply(sid)
-            new_states = applier(states, gs, counts)
+            new_states = run(states, gs, counts)
         except BaseException as exc:
             # Execution failure: the jitted applier DONATED every pending
             # shard's buffers, and the fused launch cannot attribute
@@ -1852,6 +1889,12 @@ class ShardedTickEngine:
             applied = 0
             for sid, _ in key:
                 applied += self.tick_shard(sid)
+                # Re-anchor before the next fused launch: if it fails too
+                # (a fused program that no longer fits the device, say),
+                # its rollback must not undo what these per-shard ticks
+                # applied, or every tick would replay the same pieces.
+                self._lanes[sid].ticks_since_snapshot = \
+                    self.snapshot_interval
             self.stats.n_ticks += 1
             return applied
         for (sid, _), st in zip(key, new_states):
@@ -2052,7 +2095,7 @@ class ShardedTickEngine:
                 new_state["ef"] = ef
             return new_state
 
-        return jax.jit(apply, donate_argnums=(0,)) if self._jit else apply
+        return _Applier(apply, self._jit)
 
     def _build_fleet_applier(self, key) -> Callable:
         """Compile the SINGLE-LAUNCH fleet apply for one pending pattern.
@@ -2122,6 +2165,6 @@ class ShardedTickEngine:
                 for i, (st, lo, n) in enumerate(zip(states, offsets,
                                                     lens)))
 
-        return jax.jit(apply, donate_argnums=(0,)) if self._jit else apply
+        return _Applier(apply, self._jit)
 
 

@@ -224,7 +224,12 @@ class FlatPlan:
         for seg in self.segments:
             if seg.job_id != job_id:
                 continue
-            pstart = int(np.searchsorted(own_idx, self.start(seg)))
+            # Packed position of the segment's first lane: its block's
+            # rank among the owned blocks (a search over blocks, not
+            # over the O(job bytes) lane index).
+            start = self.start(seg)
+            rank = int(np.searchsorted(blocks, start // block))
+            pstart = rank * block + start % block
             slots.append((seg.key, pstart, seg.size, seg.shape, seg.dtype))
         slots.sort(key=lambda s: s[1])
         blocks.setflags(write=False)

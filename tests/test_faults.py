@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core import ParameterService
+from repro.ps import engine as engine_mod
 from repro.ps.autoscaler import AutoscalerConfig, ElasticScaler
 from repro.ps.faults import (
     HEALTHY,
@@ -204,6 +205,35 @@ def test_sharded_transient_fault_fleet_falls_back_bit_exact():
     _assert_params_equal(rt, twin)
 
 
+def test_fleet_launch_failing_every_tick_still_drains_bit_exact():
+    """A fused program that fails on every launch while each lane's own
+    program runs (one that no longer fits the device, say) falls back per
+    shard on every tick, and no fallback undoes the per-shard progress of
+    the one before: the queues drain, one piece per job per tick."""
+    rt, eng = _sharded(snapshot_interval=4, max_staleness=8)
+    twin, teng = _sharded(snapshot_interval=4, max_staleness=8)
+
+    def out_of_memory(states, gs, counts):
+        raise RuntimeError("RESOURCE_EXHAUSTED: the fused program")
+
+    eng._build_fleet_applier = lambda key: engine_mod._Applier(
+        out_of_memory, jit=False)
+    n_steps = 6
+    for e in (eng, teng):
+        for _ in range(n_steps):
+            for j in TREES:
+                e.step(j, {"target": TARGETS[j]})
+    for _ in range(3 * n_steps):
+        if not eng.tick():
+            break
+    teng.drain()
+    assert all(eng.outstanding(j) == 0 for j in TREES)
+    s = eng.stats
+    assert s.n_fleet_fallbacks == n_steps
+    assert s.n_quarantines == 0
+    _assert_params_equal(rt, twin)
+
+
 def test_quarantine_isolates_one_lane_neighbors_tick_on():
     inj = FaultInjector()
     rt, eng = _sharded(fault_injector=inj)
@@ -249,6 +279,30 @@ def test_chaos_seeded_schedules_recover_bit_exact():
         _assert_params_equal(rt, twin)
         if inj.n_fired:
             assert eng.stats.n_rollbacks >= 1
+
+
+@pytest.mark.parametrize("mode", ["flat", "fused", "per_shard"])
+def test_compile_error_propagates_with_queues_intact(mode):
+    """A kernel the backend cannot compile is not an apply failure: the
+    error leaves the tick, every push stays queued, and nothing is rolled
+    back, retried or quarantined.  The Pallas kernel (``interpret=False``)
+    refuses the 16-element block of these plans while it lowers."""
+    svc = ParameterService(total_budget=16, n_clusters=1, plan_pad_to=16)
+    if mode == "flat":
+        rt = ServiceRuntime(svc, jit=False)
+        eng = rt.attach_engine(interpret=False)
+    else:
+        rt = ShardedServiceRuntime(svc, jit=False)
+        eng = rt.attach_engine(interpret=False, fleet_tick=mode)
+    _add_jobs(rt)
+    for j in TREES:
+        eng.submit_push(j, TARGETS[j])
+    with pytest.raises(ValueError, match="does not tile"):
+        eng.tick()
+    assert all(eng.outstanding(j) == 1 for j in TREES)
+    s = eng.stats
+    assert s.n_fleet_fallbacks == s.n_rollbacks == s.n_quarantines == 0
+    assert s.n_applied == 0
 
 
 # ----------------------------------------------------- push-piece faults
