@@ -20,6 +20,9 @@ engine.py        ServiceTickEngine: per-job bounded push queues + futures;
                  bounded-staleness (max_staleness) contract.
                  ShardedTickEngine: one independent tick loop per shard
                  space (a hot shard never stalls a cold one).
+spans.py         host spans inside the engines (``ps.push``, ``ps.tick``,
+                 ...), off by default; ``enable(True)`` records them as
+                 ``jax.profiler.TraceAnnotation``s.
 autoscaler.py    ElasticScaler: per-shard TickStats -> scale_out/scale_in
                  decisions -- the fleet follows measured load (§3.3.2).
 sharding.py      per-tensor sharding rules: the control plane's assignment

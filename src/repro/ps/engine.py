@@ -154,6 +154,7 @@ from repro.ps.runtime import (
     _split_pieces,
     _unpack_slots,
 )
+from repro.ps.spans import NO_SPAN, span
 
 __all__ = ["PullDiff", "PullVersion", "PushFuture", "ServiceTickEngine",
            "ShardedTickEngine", "TickStats"]
@@ -298,6 +299,9 @@ class TickStats:
     n_quarantines: int = 0  # lanes that exhausted retries and stopped
     n_fleet_fallbacks: int = 0  # fused fleet failures replayed per-shard
     n_lease_expirations: int = 0  # jobs reclaimed by expire_leases (PR 9)
+    # Engine program-cache misses: applier signatures compiled, and new
+    # jitted pack and pull programs (their first call compiles).
+    n_applier_compiles: int = 0
     # Wire accounting (PR 8).  Push bytes are counted at submit time with
     # the job's ``push_compression`` wire-size model (fp32 4 B/elem, bf16
     # 2, int8 1 + one fp32 scale per block); pull bytes count the payload
@@ -365,14 +369,25 @@ class PullDiff:
             indices_are_sorted=True).reshape(-1)
 
 
-def _copy_state(state):
-    """Deep copy of one state dict, device buffers COPIED (not aliased):
-    a snapshot must survive the donated apply that may consume -- or a
-    failed apply that may delete -- the live buffers, and a restored
-    copy must leave the pristine snapshot available for the NEXT
-    rollback (replay re-donates the restored buffers)."""
-    return jax.tree_util.tree_map(
-        lambda x: x.copy() if hasattr(x, "copy") else x, state)
+@jax.jit
+def state_copy(state):
+    """Deep copy of one state dict in ONE program, device buffers COPIED
+    (not aliased): a snapshot must survive the donated apply that may
+    consume -- or a failed apply that may delete -- the live buffers,
+    and a restored copy must leave the pristine snapshot available for
+    the NEXT rollback (replay re-donates the restored buffers).  Used for
+    both snapshot and restore; the host spans tell the two apart."""
+    return jax.tree_util.tree_map(jnp.copy, state)
+
+
+def _compile_span(stats: TickStats, new: bool):
+    """``ps.compile`` around the first call of a new jitted pack or pull
+    program (``new``), which traces and compiles it; counted in
+    ``stats.n_applier_compiles``."""
+    if not new:
+        return NO_SPAN
+    stats.n_applier_compiles += 1
+    return span("ps.compile")
 
 
 class _Applier:
@@ -384,14 +399,16 @@ class _Applier:
     handling, so a lowering or compile error (a kernel the chip cannot
     take) propagates to the caller instead of being rolled back, retried
     and quarantined as if an apply had failed.  Eager (``jit=False``)
-    programs have no compile step and run as they are."""
+    programs have no compile step and run as they are.  Each compile
+    counts in ``n_applier_compiles`` of every given :class:`TickStats`."""
 
-    __slots__ = ("_fn", "_jit", "_exes")
+    __slots__ = ("_fn", "_jit", "_exes", "_stats")
 
-    def __init__(self, fn: Callable, jit: bool):
+    def __init__(self, fn: Callable, jit: bool, stats=()):
         self._fn = jax.jit(fn, donate_argnums=(0,)) if jit else fn
         self._jit = jit
         self._exes: Dict[Any, Callable] = {}
+        self._stats = stats
 
     def compiled(self, *args) -> Callable:
         if not self._jit:
@@ -400,7 +417,10 @@ class _Applier:
         sig = (tree, tuple(jax.typeof(x) for x in leaves))
         exe = self._exes.get(sig)
         if exe is None:
-            exe = self._exes[sig] = self._fn.lower(*args).compile()
+            with span("ps.compile"):
+                exe = self._exes[sig] = self._fn.lower(*args).compile()
+            for stats in self._stats:
+                stats.n_applier_compiles += 1
         return exe
 
 
@@ -737,32 +757,33 @@ class ServiceTickEngine:
             # trainer silently stale parameters.  Read-tier replicas
             # (repro.ps.replica) are the degraded-serving path.
             raise self.quarantine_error
-        self._queue(job_id)  # validates the job id
-        while self.outstanding(job_id) > self.max_staleness:
-            self.stats.n_forced_staleness += 1
-            self.tick()
-        if since_version is not None:
-            return self._pull_versioned(job_id, since_version)
-        layout = self.plan.job_layout(job_id)
-        self.stats.n_full_pulls += 1
-        self.stats.pull_bytes_wire += 4 * layout.packed_len
-        self.stats.pull_bytes_full += 4 * layout.packed_len
-        fn = self._pull_fns.get(job_id)
-        if fn is None:
-            plan = self.plan
-            layout = plan.job_layout(job_id)
-            abstract = self.runtime._jobs[job_id]["abstract"]
-            rows = jnp.asarray(layout.blocks)
+        with span("ps.pull", job=job_id):
+            self._queue(job_id)  # validates the job id
+            while self.outstanding(job_id) > self.max_staleness:
+                self.stats.n_forced_staleness += 1
+                self.tick()
+            if since_version is not None:
+                return self._pull_versioned(job_id, since_version)
+            layout = self.plan.job_layout(job_id)
+            self.stats.n_full_pulls += 1
+            self.stats.pull_bytes_wire += 4 * layout.packed_len
+            self.stats.pull_bytes_full += 4 * layout.packed_len
+            fn = self._pull_fns.get(job_id)
+            new = fn is None and self._jit
+            if fn is None:
+                abstract = self.runtime._jobs[job_id]["abstract"]
+                rows = jnp.asarray(layout.blocks)
 
-            def fn(flat, _layout=layout, _rows=rows, _abstract=abstract):
-                packed = (flat if _layout.covers_all else
-                          flat.reshape(-1, _layout.block)[_rows].reshape(-1))
-                return _unpack_slots(_layout, packed, _abstract)
+                def pull_gather(flat, _layout=layout, _rows=rows,
+                                _abstract=abstract):
+                    packed = (flat if _layout.covers_all else flat.reshape(
+                        -1, _layout.block)[_rows].reshape(-1))
+                    return _unpack_slots(_layout, packed, _abstract)
 
-            if self._jit:
-                fn = jax.jit(fn)
-            self._pull_fns[job_id] = fn
-        return fn(self.runtime.state["flat"])
+                fn = jax.jit(pull_gather) if self._jit else pull_gather
+                self._pull_fns[job_id] = fn
+            with _compile_span(self.stats, new):
+                return fn(self.runtime.state["flat"])
 
     # ----------------------------------------------------- versioned pulls
     def _versions_array(self) -> np.ndarray:
@@ -823,19 +844,26 @@ class ServiceTickEngine:
         """Queue a job's gradient pytree for the next tick; returns a
         future.  A full queue exerts backpressure: the submit first forces
         ticks until a slot frees up."""
-        q = self._queue(job_id)
-        while len(q) >= self.queue_capacity:
-            self.stats.n_forced_capacity += 1
-            self.tick()
-        fn = self._pack_fns.get(job_id)
-        if fn is None:
-            layout = self.plan.job_layout(job_id)
-            fn = (lambda grads, _layout=layout:
-                  _pack_slots(_layout, grads))
-            if self._jit:
-                fn = jax.jit(fn)
-            self._pack_fns[job_id] = fn
-        return self.submit_packed(job_id, fn(grads))
+        with span("ps.push", job=job_id) as sp:
+            q = self._queue(job_id)
+            while len(q) >= self.queue_capacity:
+                self.stats.n_forced_capacity += 1
+                self.tick()
+            # The step count this push applies with, barring faults.
+            sp.set_metadata(step=self._counts[job_id] + len(q) + 1)
+            fn = self._pack_fns.get(job_id)
+            new = fn is None and self._jit
+            if fn is None:
+                layout = self.plan.job_layout(job_id)
+
+                def push_pack(grads, _layout=layout):
+                    return _pack_slots(_layout, grads)
+
+                fn = jax.jit(push_pack) if self._jit else push_pack
+                self._pack_fns[job_id] = fn
+            with _compile_span(self.stats, new):
+                packed = fn(grads)
+            return self.submit_packed(job_id, packed)
 
     def submit_packed(self, job_id: str, packed) -> PushFuture:
         """Queue an ALREADY-PACKED job-local gradient vector (the layout's
@@ -886,16 +914,15 @@ class ServiceTickEngine:
             abstract, loss_fn = info["abstract"], info["loss_fn"]
             rows = jnp.asarray(layout.blocks)
 
-            def fn(flat, batch, _layout=layout, _rows=rows,
-                   _abstract=abstract, _loss=loss_fn):
+            def job_grads(flat, batch, _layout=layout, _rows=rows,
+                          _abstract=abstract, _loss=loss_fn):
                 packed = (flat if _layout.covers_all else
                           flat.reshape(-1, _layout.block)[_rows].reshape(-1))
                 params = _unpack_slots(_layout, packed, _abstract)
                 loss, grads = jax.value_and_grad(_loss)(params, batch)
                 return loss, _pack_slots(_layout, grads)
 
-            if self._jit:
-                fn = jax.jit(fn)
+            fn = jax.jit(job_grads) if self._jit else job_grads
             self._grad_fns[job_id] = fn
         loss, packed = fn(self.runtime.state["flat"], batch)
         return {"loss": loss, "future": self._enqueue(q, job_id, packed)}
@@ -910,10 +937,15 @@ class ServiceTickEngine:
         (0 = nothing pending)."""
         if self.health == QUARANTINED:
             raise self.quarantine_error
+        with span("ps.tick", tick=self.stats.n_ticks) as sp:
+            return self._tick(only, sp)
+
+    def _tick(self, only, sp) -> int:
         pending = [j for j in self.runtime._jobs
                    if self._queues.get(j) and (only is None or j in only)]
         if not pending:
             return 0
+        sp.set_metadata(pieces=len(pending))
         # Epoch fence: a queued push packed under a different plan epoch
         # must never reach the apply -- touched jobs are drained before
         # the plan changes and untouched survivors are re-tagged, so a
@@ -968,7 +1000,8 @@ class ServiceTickEngine:
             try:
                 if self.fault_injector is not None:
                     self.fault_injector.on_apply(None)
-                self.runtime.state = run(self.runtime.state, gs)
+                with span("ps.launch"):
+                    self.runtime.state = run(self.runtime.state, gs)
             except BaseException as exc:
                 # Execution failure: the jitted applier DONATES the state
                 # buffers, so they may already be deleted.  Re-queue the
@@ -1006,8 +1039,9 @@ class ServiceTickEngine:
             return False
         if (self._snapshot is None
                 or self._ticks_since_snapshot >= self.snapshot_interval):
-            self._snapshot = (_copy_state(self.runtime.state),
-                              dict(self._counts))
+            with span("ps.snapshot"):
+                self._snapshot = (state_copy(self.runtime.state),
+                                  dict(self._counts))
             self._snapshot_log = []
             self._ticks_since_snapshot = 0
             self.stats.n_snapshots += 1
@@ -1020,22 +1054,23 @@ class ServiceTickEngine:
         subsequent ticks replay the identical sequence.  Replayed
         futures ride along un-resolved-if-pending / kept-done-if-done;
         the snapshot itself stays pristine for a repeated rollback."""
-        state_copy, counts_copy = self._snapshot
-        self.runtime.state = _copy_state(state_copy)
-        self._counts = dict(counts_copy)
-        # The restore REWOUND every block the logged pushes had touched:
-        # re-stamp them so a diff-pull client who saw the undone values
-        # is told those blocks changed (versions only move forward).
-        self._stamp_blocks({j for j, _, _ in self._snapshot_log})
-        for j, packed, fut in reversed(self._snapshot_log):
-            if fut is not None:
-                fut._unresolve()
-            self._queues.setdefault(j, deque()).appendleft(
-                (packed, fut, self._epoch))
-            self.stats.n_replayed += 1
-        self._snapshot_log = []
-        self._ticks_since_snapshot = 0
-        self.stats.n_rollbacks += 1
+        with span("ps.rollback"):
+            snapshot, counts_copy = self._snapshot
+            self.runtime.state = state_copy(snapshot)
+            self._counts = dict(counts_copy)
+            # The restore REWOUND every block the logged pushes had touched:
+            # re-stamp them so a diff-pull client who saw the undone values
+            # is told those blocks changed (versions only move forward).
+            self._stamp_blocks({j for j, _, _ in self._snapshot_log})
+            for j, packed, fut in reversed(self._snapshot_log):
+                if fut is not None:
+                    fut._unresolve()
+                self._queues.setdefault(j, deque()).appendleft(
+                    (packed, fut, self._epoch))
+                self.stats.n_replayed += 1
+            self._snapshot_log = []
+            self._ticks_since_snapshot = 0
+            self.stats.n_rollbacks += 1
 
     def _handle_apply_failure(self, exc: BaseException, key) -> None:
         """Roll back and return (the tick swallows the failure; later
@@ -1113,7 +1148,7 @@ class ServiceTickEngine:
                       for i, info in enumerate(infos)
                       if (kind := info["step_opts"].get("push_compression"))]
 
-        def apply(state, gs):
+        def flat_apply(state, gs):
             counts = [state["counts"][j] + 1 for j in job_ids]
             if compressed:
                 ef = state.get("ef")
@@ -1137,7 +1172,7 @@ class ServiceTickEngine:
             return new_state
 
         # Donate the shared state: flat/mu/nu update in place per tick.
-        return _Applier(apply, self._jit)
+        return _Applier(flat_apply, self._jit, (self.stats,))
 
 
 # --------------------------------------------------------------- sharded
@@ -1401,42 +1436,45 @@ class ShardedTickEngine:
         owned blocks whose version moved since the client's
         :class:`PullVersion` -- versions concatenate over the hosting
         shards in shard order, matching the packed piece order."""
-        layout = self._layout(job_id)
-        for sid in layout.shard_ids:
-            lane = self._lanes.get(sid)
-            if lane is not None and lane.health == QUARANTINED:
-                # A hosting lane froze at its last-good snapshot and will
-                # never advance: raise its error instead of serving
-                # silently stale parameters.  Read-tier replicas
-                # (repro.ps.replica) are the degraded-serving path.
-                raise lane.quarantine_error
-        while self.outstanding(job_id) > self.max_staleness:
-            self.stats.n_forced_staleness += 1
-            if self.tick() == 0:
-                stall = self._stall_error(job_id)
-                if stall is not None:
-                    # The backlog lives on a quarantined lane: forcing
-                    # more ticks can never drain it.
-                    raise stall
-        if since_version is not None:
-            return self._pull_versioned(job_id, layout, since_version)
-        self.stats.n_full_pulls += 1
-        self.stats.pull_bytes_wire += 4 * layout.packed_len
-        self.stats.pull_bytes_full += 4 * layout.packed_len
-        fn = self._pull_fns.get(job_id)
-        if fn is None:
-            abstract = self.runtime._jobs[job_id]["abstract"]
-            rows = _layout_rows(layout)
+        with span("ps.pull", job=job_id):
+            layout = self._layout(job_id)
+            for sid in layout.shard_ids:
+                lane = self._lanes.get(sid)
+                if lane is not None and lane.health == QUARANTINED:
+                    # A hosting lane froze at its last-good snapshot and will
+                    # never advance: raise its error instead of serving
+                    # silently stale parameters.  Read-tier replicas
+                    # (repro.ps.replica) are the degraded-serving path.
+                    raise lane.quarantine_error
+            while self.outstanding(job_id) > self.max_staleness:
+                self.stats.n_forced_staleness += 1
+                if self.tick() == 0:
+                    stall = self._stall_error(job_id)
+                    if stall is not None:
+                        # The backlog lives on a quarantined lane: forcing
+                        # more ticks can never drain it.
+                        raise stall
+            if since_version is not None:
+                return self._pull_versioned(job_id, layout, since_version)
+            self.stats.n_full_pulls += 1
+            self.stats.pull_bytes_wire += 4 * layout.packed_len
+            self.stats.pull_bytes_full += 4 * layout.packed_len
+            fn = self._pull_fns.get(job_id)
+            new = fn is None and self._jit
+            if fn is None:
+                abstract = self.runtime._jobs[job_id]["abstract"]
+                rows = _layout_rows(layout)
 
-            def fn(flats, _layout=layout, _rows=rows, _abstract=abstract):
-                p = _gather_packed(_layout, _rows, flats)
-                return _unpack_slots(_layout, p, _abstract)
+                def pull_gather(flats, _layout=layout, _rows=rows,
+                                _abstract=abstract):
+                    p = _gather_packed(_layout, _rows, flats)
+                    return _unpack_slots(_layout, p, _abstract)
 
-            if self._jit:
-                fn = jax.jit(fn)
-            self._pull_fns[job_id] = fn
-        return fn(tuple(self.runtime.states[sid]["flat"]
-                        for sid in layout.shard_ids))
+                fn = jax.jit(pull_gather) if self._jit else pull_gather
+                self._pull_fns[job_id] = fn
+            with _compile_span(self.stats, new):
+                return fn(tuple(self.runtime.states[sid]["flat"]
+                                for sid in layout.shard_ids))
 
     # ----------------------------------------------------- versioned pulls
     def _lane_versions(self, lane: _ShardLane) -> np.ndarray:
@@ -1570,18 +1608,22 @@ class ShardedTickEngine:
     def submit_push(self, job_id: str, grads) -> PushFuture:
         """Queue a job's gradient pytree: one packed piece per hosting
         shard, applied by each shard's own ticks."""
-        layout = self._layout(job_id)
-        self._force_capacity(job_id, layout)
-        fn = self._pack_fns.get(job_id)
-        if fn is None:
-            def fn(grads, _layout=layout):
-                g = _pack_slots(_layout, grads)
-                return _split_pieces(_layout, g)
+        with span("ps.push", job=job_id) as sp:
+            layout = self._layout(job_id)
+            self._force_capacity(job_id, layout)
+            sp.set_metadata(step=self._counts[job_id] + 1)
+            fn = self._pack_fns.get(job_id)
+            new = fn is None and self._jit
+            if fn is None:
+                def push_pack(grads, _layout=layout):
+                    g = _pack_slots(_layout, grads)
+                    return _split_pieces(_layout, g)
 
-            if self._jit:
-                fn = jax.jit(fn)
-            self._pack_fns[job_id] = fn
-        return self._enqueue(job_id, layout, fn(grads))
+                fn = jax.jit(push_pack) if self._jit else push_pack
+                self._pack_fns[job_id] = fn
+            with _compile_span(self.stats, new):
+                pieces = fn(grads)
+            return self._enqueue(job_id, layout, pieces)
 
     def step(self, job_id: str, batch) -> Dict[str, Any]:
         """One engine-mode iteration: staleness-bounded pull, loss/grads,
@@ -1600,8 +1642,8 @@ class ShardedTickEngine:
             abstract, loss_fn = info["abstract"], info["loss_fn"]
             rows = _layout_rows(layout)
 
-            def fn(flats, batch, _layout=layout, _rows=rows,
-                   _abstract=abstract, _loss=loss_fn):
+            def job_grads(flats, batch, _layout=layout, _rows=rows,
+                          _abstract=abstract, _loss=loss_fn):
                 params = _unpack_slots(
                     _layout, _gather_packed(_layout, _rows, flats),
                     _abstract)
@@ -1609,8 +1651,7 @@ class ShardedTickEngine:
                 return loss, _split_pieces(_layout, _pack_slots(_layout,
                                                                 grads))
 
-            if self._jit:
-                fn = jax.jit(fn)
+            fn = jax.jit(job_grads) if self._jit else job_grads
             self._grad_fns[job_id] = fn
         loss, pieces = fn(
             tuple(self.runtime.states[sid]["flat"]
@@ -1626,6 +1667,10 @@ class ShardedTickEngine:
         are untouched -- this is the independent cadence primitive, and
         the unit of failure isolation: a QUARANTINED lane is skipped
         (returns 0) so its neighbors' cadence never stalls."""
+        with span("ps.lane_tick", shard=shard_id):
+            return self._tick_shard(shard_id, only)
+
+    def _tick_shard(self, shard_id: str, only) -> int:
         lane = self._lanes.get(shard_id)
         if lane is None or lane.health == QUARANTINED:
             return 0
@@ -1673,8 +1718,9 @@ class ShardedTickEngine:
             try:
                 if self.fault_injector is not None:
                     self.fault_injector.on_apply(shard_id)
-                self.runtime.states[shard_id] = run(
-                    self.runtime.states[shard_id], gs, counts)
+                with span("ps.launch"):
+                    self.runtime.states[shard_id] = run(
+                        self.runtime.states[shard_id], gs, counts)
             except BaseException as exc:
                 # Execution failure: the jitted applier DONATED this
                 # shard's buffers.  Re-queue the heads, restore the
@@ -1722,7 +1768,9 @@ class ShardedTickEngine:
             return False
         if (lane.snapshot is None
                 or lane.ticks_since_snapshot >= self.snapshot_interval):
-            lane.snapshot = _copy_state(self.runtime.states[lane.shard_id])
+            with span("ps.snapshot"):
+                lane.snapshot = state_copy(
+                    self.runtime.states[lane.shard_id])
             lane.log = []
             lane.ticks_since_snapshot = 0
             lane.stats.n_snapshots += 1
@@ -1735,21 +1783,22 @@ class ShardedTickEngine:
         pieces IN FRONT of the queued backlog (per-job order preserved):
         subsequent ticks replay the identical (piece, count) sequence,
         which is bit-exact because counts were fixed at submit time."""
-        self.runtime.states[lane.shard_id] = _copy_state(lane.snapshot)
-        # The restore rewound the logged jobs' blocks: re-stamp so diff
-        # clients who saw the undone values are told they changed.
-        self._stamp_lane(lane, {j for j, _, _, _ in lane.log})
-        for j, piece, count, fut in reversed(lane.log):
-            if fut is not None:
-                fut._unresolve()
-            lane.queues.setdefault(j, deque()).appendleft(
-                (piece, count, fut, self._epoch))
-            lane.stats.n_replayed += 1
-            self.stats.n_replayed += 1
-        lane.log = []
-        lane.ticks_since_snapshot = 0
-        lane.stats.n_rollbacks += 1
-        self.stats.n_rollbacks += 1
+        with span("ps.rollback"):
+            self.runtime.states[lane.shard_id] = state_copy(lane.snapshot)
+            # The restore rewound the logged jobs' blocks: re-stamp so diff
+            # clients who saw the undone values are told they changed.
+            self._stamp_lane(lane, {j for j, _, _, _ in lane.log})
+            for j, piece, count, fut in reversed(lane.log):
+                if fut is not None:
+                    fut._unresolve()
+                lane.queues.setdefault(j, deque()).appendleft(
+                    (piece, count, fut, self._epoch))
+                lane.stats.n_replayed += 1
+                self.stats.n_replayed += 1
+            lane.log = []
+            lane.ticks_since_snapshot = 0
+            lane.stats.n_rollbacks += 1
+            self.stats.n_rollbacks += 1
 
     def _handle_lane_failure(self, lane: _ShardLane, exc: BaseException,
                              key) -> None:
@@ -1800,6 +1849,10 @@ class ShardedTickEngine:
         recovery), so one dead shard never blocks the fleet launch.
         Returns pieces applied across the fleet (0 = nothing pending
         anywhere)."""
+        with span("ps.tick", tick=self.stats.n_ticks) as sp:
+            return self._tick_fleet(only, sp)
+
+    def _tick_fleet(self, only, sp) -> int:
         plan = self.plan
         if plan is None:
             return 0
@@ -1825,6 +1878,7 @@ class ShardedTickEngine:
         if not entries:
             return 0
         key = tuple(entries)
+        sp.set_metadata(pieces=sum(len(jobs) for _, jobs in key))
         # Build and compile BEFORE popping: a build failure (e.g. mixed
         # block_align across lanes) or a compile failure leaves every
         # queue untouched and propagates -- neither is an apply failure.
@@ -1856,7 +1910,8 @@ class ShardedTickEngine:
             if self.fault_injector is not None:
                 for sid, _ in key:
                     self.fault_injector.on_apply(sid)
-            new_states = run(states, gs, counts)
+            with span("ps.launch"):
+                new_states = run(states, gs, counts)
         except BaseException as exc:
             # Execution failure: the jitted applier DONATED every pending
             # shard's buffers, and the fused launch cannot attribute
@@ -1884,17 +1939,19 @@ class ShardedTickEngine:
                 self.stats.n_ticks += 1
                 return 0
             self.stats.n_fleet_fallbacks += 1
-            for sid, _ in key:
-                self._rollback_lane(self._lanes[sid])
             applied = 0
-            for sid, _ in key:
-                applied += self.tick_shard(sid)
-                # Re-anchor before the next fused launch: if it fails too
-                # (a fused program that no longer fits the device, say),
-                # its rollback must not undo what these per-shard ticks
-                # applied, or every tick would replay the same pieces.
-                self._lanes[sid].ticks_since_snapshot = \
-                    self.snapshot_interval
+            with span("ps.fallback"):
+                for sid, _ in key:
+                    self._rollback_lane(self._lanes[sid])
+                for sid, _ in key:
+                    applied += self.tick_shard(sid)
+                    # Re-anchor before the next fused launch: if it fails
+                    # too (a fused program that no longer fits the device,
+                    # say), its rollback must not undo what these
+                    # per-shard ticks applied, or every tick would replay
+                    # the same pieces.
+                    self._lanes[sid].ticks_since_snapshot = \
+                        self.snapshot_interval
             self.stats.n_ticks += 1
             return applied
         for (sid, _), st in zip(key, new_states):
@@ -2072,7 +2129,7 @@ class ShardedTickEngine:
                       for i, info in enumerate(infos)
                       if (kind := info["step_opts"].get("push_compression"))]
 
-        def apply(state, gs, counts):
+        def lane_apply(state, gs, counts):
             # Counts arrive as the pieces' submit-time step numbers; lift
             # to arrays so eager mode matches the traced path exactly.
             counts = [jnp.asarray(c, jnp.int32) for c in counts]
@@ -2095,7 +2152,8 @@ class ShardedTickEngine:
                 new_state["ef"] = ef
             return new_state
 
-        return _Applier(apply, self._jit)
+        return _Applier(lane_apply, self._jit,
+                        (self._lane(shard_id).stats, self.stats))
 
     def _build_fleet_applier(self, key) -> Callable:
         """Compile the SINGLE-LAUNCH fleet apply for one pending pattern.
@@ -2165,6 +2223,7 @@ class ShardedTickEngine:
                 for i, (st, lo, n) in enumerate(zip(states, offsets,
                                                     lens)))
 
-        return _Applier(apply, self._jit)
+        # Keeps the name ``apply``: a trace shows it as ``jit_apply``.
+        return _Applier(apply, self._jit, (self.stats,))
 
 
