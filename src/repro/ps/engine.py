@@ -79,7 +79,9 @@ earlier engines poisoned the WHOLE engine permanently.  Now every lane
 (each shard space; the flat engine is one unnamed lane) keeps a
 last-good SNAPSHOT of its state, refreshed every ``snapshot_interval``
 applying ticks with the copy taken *before* the donated apply, plus a
-replay log of the pushes applied since.  On an exec failure the lane
+replay log of the pushes applied since.  The anchor also refreshes
+sooner when the log would come to hold more bytes than the snapshot,
+so the log never outgrows it.  On an exec failure the lane
 restores the snapshot, re-queues the failed heads AND the logged
 pushes (in order, futures kept but never re-resolved), and replays them
 on subsequent ticks -- at ``max_staleness=0`` the recovered trajectory
@@ -196,7 +198,8 @@ class PushFuture:
     def rolled_back(self) -> bool:
         """True if this push HAD applied but its effect was discarded by
         ``recover_shard`` (it was inside the lost shard's rollback
-        window, at most ``snapshot_interval`` ticks deep)."""
+        window: at most ``snapshot_interval`` ticks deep, and at most a
+        replay log as large as the shard's snapshot)."""
         return self._rolled_back
 
     def result(self, timeout: Optional[float] = None) -> int:
@@ -294,6 +297,9 @@ class TickStats:
     n_replans: int = 0  # plan changes the engine rode through
     n_retagged: int = 0  # untouched pushes carried across a replan (fence)
     n_snapshots: int = 0  # last-good state copies taken (rollback anchors)
+    # ... of which refreshed because the replay log would have outgrown
+    # the state's bytes, before snapshot_interval ticks had passed
+    n_snapshots_by_bytes: int = 0
     n_rollbacks: int = 0  # failed applies recovered by snapshot restore
     n_replayed: int = 0  # applied pushes re-queued for replay by rollbacks
     n_quarantines: int = 0  # lanes that exhausted retries and stopped
@@ -378,6 +384,24 @@ def state_copy(state):
     the NEXT rollback (replay re-donates the restored buffers).  Used for
     both snapshot and restore; the host spans tell the two apart."""
     return jax.tree_util.tree_map(jnp.copy, state)
+
+
+def _anchor_due(anchor, ticks_since: int, interval: int, log,
+                incoming: int, state) -> Optional[str]:
+    """Why a lane's rollback anchor must be refreshed before this tick's
+    apply, or None.  ``"ticks"``: there is no anchor yet, or ``interval``
+    applying ticks have passed since it.  ``"bytes"``: the replay log
+    plus the ``incoming`` bytes of the pieces this tick will log would
+    hold more bytes than the state, which is what the snapshot holds --
+    so a log never outgrows its snapshot.  The log's bytes are summed
+    from its entries (the piece is each entry's second field), at most
+    ``interval`` ticks of them, so no counter has to follow the log
+    through refreshes, rollbacks and job removals."""
+    if anchor is None or ticks_since >= interval:
+        return "ticks"
+    logged = sum(entry[1].nbytes for entry in log)
+    held = sum(x.nbytes for x in jax.tree_util.tree_leaves(state))
+    return "bytes" if logged + incoming > held else None
 
 
 def _compile_span(stats: TickStats, new: bool):
@@ -968,7 +992,7 @@ class ServiceTickEngine:
         # Refresh the lane snapshot BEFORE any donated apply can consume
         # the live buffers (queues are still intact, so the snapshot plus
         # the -- now empty -- replay log reconstructs this exact moment).
-        snapped = self._maybe_snapshot()
+        snapped = self._maybe_snapshot(pending)
         if self._replica_hub is not None:
             # Publish point for the read tier, co-located with the
             # rollback snapshot: on a refresh tick the hub rides the copy
@@ -1030,23 +1054,32 @@ class ServiceTickEngine:
         return applied
 
     # ------------------------------------------------------- fault recovery
-    def _maybe_snapshot(self) -> bool:
-        """Copy (state, counts mirror) as the rollback anchor, every
-        ``snapshot_interval`` applying ticks, BEFORE the donated apply.
+    def _maybe_snapshot(self, jobs) -> bool:
+        """Copy (state, counts mirror) as the rollback anchor, BEFORE the
+        donated apply: every ``snapshot_interval`` applying ticks, or
+        sooner when the replay log plus the head pushes of ``jobs`` this
+        tick will log would outgrow the state (:func:`_anchor_due`).
         Returns True when the anchor was refreshed this call (the read
         tier reuses its fresh copy instead of taking another)."""
         if self.snapshot_interval <= 0:
             return False
-        if (self._snapshot is None
-                or self._ticks_since_snapshot >= self.snapshot_interval):
-            with span("ps.snapshot"):
-                self._snapshot = (state_copy(self.runtime.state),
-                                  dict(self._counts))
-            self._snapshot_log = []
-            self._ticks_since_snapshot = 0
-            self.stats.n_snapshots += 1
-            return True
-        return False
+        by = _anchor_due(self._snapshot, self._ticks_since_snapshot,
+                         self.snapshot_interval, self._snapshot_log,
+                         sum(self._queues[j][0][0].nbytes for j in jobs),
+                         self.runtime.state)
+        if by is None:
+            return False
+        # Let go of the old anchor and its log before copying: the copy
+        # then never needs room beside them (a tick that raises here has
+        # popped nothing, and the next one copies again).
+        self._snapshot, self._snapshot_log = None, []
+        with span("ps.snapshot", by=by):
+            self._snapshot = (state_copy(self.runtime.state),
+                              dict(self._counts))
+        self._ticks_since_snapshot = 0
+        self.stats.n_snapshots += 1
+        self.stats.n_snapshots_by_bytes += int(by == "bytes")
+        return True
 
     def _rollback(self) -> None:
         """Restore the last-good snapshot and re-queue the logged pushes
@@ -1690,7 +1723,7 @@ class ShardedTickEngine:
             lane.stats.n_per_job_dispatch += 1
         else:
             groups = [tuple(pending)]
-        snapped = self._maybe_snapshot_lane(lane)
+        snapped = self._maybe_snapshot_lane(lane, pending)
         if self._replica_hub is not None:
             # Read-tier publish point, co-located with the rollback
             # snapshot so a refresh tick's copy is shared, not repeated.
@@ -1758,25 +1791,35 @@ class ShardedTickEngine:
         return applied
 
     # ------------------------------------------------------- fault recovery
-    def _maybe_snapshot_lane(self, lane: _ShardLane) -> bool:
-        """Refresh this lane's rollback anchor every ``snapshot_interval``
-        of ITS applying ticks, BEFORE the donated apply (queues intact,
-        replay log emptied: snapshot + log reconstructs any later
-        moment).  Returns True when the anchor was refreshed this call
-        (the read tier reuses its fresh copy instead of taking another)."""
+    def _maybe_snapshot_lane(self, lane: _ShardLane, jobs) -> bool:
+        """Refresh this lane's rollback anchor BEFORE the donated apply
+        (queues intact, replay log emptied: snapshot + log reconstructs
+        any later moment): every ``snapshot_interval`` of ITS applying
+        ticks, or sooner when its replay log plus the head pieces of
+        ``jobs`` this tick will apply on it would outgrow its state
+        (:func:`_anchor_due`).  Returns True when the anchor was
+        refreshed this call (the read tier reuses its fresh copy instead
+        of taking another)."""
         if self.snapshot_interval <= 0:
             return False
-        if (lane.snapshot is None
-                or lane.ticks_since_snapshot >= self.snapshot_interval):
-            with span("ps.snapshot"):
-                lane.snapshot = state_copy(
-                    self.runtime.states[lane.shard_id])
-            lane.log = []
-            lane.ticks_since_snapshot = 0
-            lane.stats.n_snapshots += 1
-            self.stats.n_snapshots += 1
-            return True
-        return False
+        state = self.runtime.states[lane.shard_id]
+        by = _anchor_due(lane.snapshot, lane.ticks_since_snapshot,
+                         self.snapshot_interval, lane.log,
+                         sum(lane.queues[j][0][0].nbytes for j in jobs),
+                         state)
+        if by is None:
+            return False
+        # Let go of the old anchor and its log before copying (see
+        # ServiceTickEngine._maybe_snapshot): beside them, a copy near
+        # the device's capacity blocks its dispatch with the device idle.
+        lane.snapshot, lane.log = None, []
+        with span("ps.snapshot", by=by):
+            lane.snapshot = state_copy(state)
+        lane.ticks_since_snapshot = 0
+        for stats in (lane.stats, self.stats):
+            stats.n_snapshots += 1
+            stats.n_snapshots_by_bytes += int(by == "bytes")
+        return True
 
     def _rollback_lane(self, lane: _ShardLane) -> None:
         """Restore the lane's last-good state and re-queue its logged
@@ -1897,8 +1940,8 @@ class ShardedTickEngine:
         # Snapshot every participating lane BEFORE popping: queues are
         # intact, so each lane's (snapshot, empty log) anchors a rollback
         # of this very launch.
-        for sid, _ in key:
-            snapped = self._maybe_snapshot_lane(self._lanes[sid])
+        for sid, jobs in key:
+            snapped = self._maybe_snapshot_lane(self._lanes[sid], jobs)
             if self._replica_hub is not None:
                 self._replica_hub.on_tick(sid, snapped)
         popped = []  # (sid, job, head) in key order == table order

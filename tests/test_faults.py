@@ -142,7 +142,10 @@ def test_injector_rules_match_shard_and_occurrence():
 # ------------------------------------------------------ flat engine faults
 def test_flat_transient_fault_recovers_bit_exact():
     inj = FaultInjector()
-    inj.fail_apply(at=4).fail_apply(at=9)
+    # The first fault lands on a tick whose anchor the byte bound just
+    # refreshed (nothing to replay), the second on the last tick, one
+    # logged tick past the next such refresh.
+    inj.fail_apply(at=4).fail_apply(at=8)
     rt, eng = _flat(snapshot_interval=4, fault_injector=inj)
     twin, teng = _flat(snapshot_interval=4)
     _drive(eng, 8)
@@ -355,7 +358,8 @@ def test_recover_shard_rehosts_and_training_continues():
     assert victim not in rt.shard_ids
     assert victim not in eng._lanes
     # The rollback window is bounded: at most snapshot_interval ticks of
-    # pushes were discarded or cancelled with the lane.
+    # pushes, and at most a replay log as large as the lane's snapshot,
+    # were discarded or cancelled with the lane.
     assert (report.rolled_back_pushes + report.cancelled_pushes
             <= 4 * len(TREES) + len(TREES))
     # The fleet is whole again: every job trains and drains.
@@ -384,6 +388,114 @@ def test_recover_shard_unknown_id_raises():
     rt, _ = _sharded()
     with pytest.raises(ValueError, match="unknown shard"):
         rt.recover_shard("nope/agg9")
+
+
+# ------------------------------------------- rollback log bounded by bytes
+def _engine(kind, **engine_opts):
+    return (_flat if kind == "flat" else _sharded)(**engine_opts)
+
+
+def _anchors(rt, eng):
+    """(stats, replay log, state) of each lane: the flat engine's one
+    unnamed lane, or each shard space's."""
+    if kind_of(eng) == "flat":
+        return {None: (eng.stats, eng._snapshot_log, rt.state)}
+    return {sid: (lane.stats, lane.log, rt.states[sid])
+            for sid, lane in eng._lanes.items()}
+
+
+def kind_of(eng):
+    return "flat" if isinstance(eng, engine_mod.ServiceTickEngine) \
+        else "sharded"
+
+
+def _nbytes(log, state):
+    return (sum(entry[1].nbytes for entry in log),
+            sum(4 * x.size for x in jax.tree_util.tree_leaves(state)))
+
+
+@pytest.mark.parametrize("kind", ["flat", "sharded"])
+def test_replay_log_never_outgrows_its_snapshot(kind):
+    """Every job pushes its whole space each tick, a third of the
+    (flat, mu, nu) state: the log reaches the state's bytes in three
+    ticks, and the anchor refreshes there, long before the interval."""
+    rt, eng = _engine(kind, snapshot_interval=8)
+    n_rounds, largest = 12, 0
+    for _ in range(n_rounds):
+        _drive(eng, 1)
+        for _, log, state in _anchors(rt, eng).values():
+            logged, held = _nbytes(log, state)
+            assert logged <= held
+            largest = max(largest, logged / held)
+    s = eng.stats
+    assert s.n_ticks == n_rounds
+    assert 0 < s.n_snapshots_by_bytes <= s.n_snapshots < n_rounds * len(
+        _anchors(rt, eng))
+    assert largest > 0.5  # the log does fill up to its bound
+    assert rt.debug_stats()["engine"]["n_snapshots_by_bytes"] == \
+        s.n_snapshots_by_bytes
+
+
+@pytest.mark.parametrize("kind", ["flat", "sharded"])
+def test_small_pieces_keep_the_tick_interval(kind):
+    """One job of three pushes: its pieces stay small against the space,
+    so the anchor refreshes every snapshot_interval ticks, as before."""
+    rt, eng = _engine(kind, **({} if kind == "flat" else {"n_shards": 1}),
+                      snapshot_interval=4)
+    for t in range(1, 11):
+        eng.step("b", {"target": TARGETS["b"]})
+        eng.drain()
+        assert eng.stats.n_ticks == t
+        assert eng.stats.n_snapshots == (t + 3) // 4  # ticks 1, 5, 9
+    assert eng.stats.n_snapshots_by_bytes == 0
+    for _, log, state in _anchors(rt, eng).values():
+        logged, held = _nbytes(log, state)
+        assert 0 < logged <= held // 3
+
+
+@pytest.mark.parametrize("after", [0, 1])
+@pytest.mark.parametrize("kind", ["flat", "sharded"])
+def test_fault_after_byte_refresh_recovers_bit_exact(kind, after):
+    """A fault on the launch of the tick whose anchor the byte bound
+    refreshed (``after`` 0: nothing logged yet) or of the tick after it
+    (1: one tick to replay) restores that anchor and lands on the
+    fault-free twin bit for bit."""
+    twin, teng = _engine(kind, snapshot_interval=8)
+    lane = None if kind == "flat" else twin.shard_ids[-1]
+    first = None
+    for t in range(1, 9):
+        _drive(teng, 1)
+        if first is None and _anchors(twin, teng)[lane][0].\
+                n_snapshots_by_bytes:
+            first = t
+    assert first is not None and first < 8
+    inj = FaultInjector()
+    inj.fail_apply(lane, at=first + after)
+    rt, eng = _engine(kind, snapshot_interval=8, fault_injector=inj)
+    _drive(eng, 8)
+    assert inj.n_fired == 1
+    assert eng.stats.n_rollbacks >= 1
+    assert eng.stats.n_quarantines == 0
+    assert _anchors(rt, eng)[lane][0].n_snapshots_by_bytes >= 1
+    _assert_params_equal(rt, twin)
+
+
+@pytest.mark.parametrize("kind", ["flat", "sharded"])
+def test_snapshot_span_names_the_rule_that_fired(kind, monkeypatch):
+    rules = []
+    real = engine_mod.span
+
+    def record(name, **meta):
+        if name == "ps.snapshot":
+            rules.append(meta["by"])
+        return real(name, **meta)
+
+    monkeypatch.setattr(engine_mod, "span", record)
+    rt, eng = _engine(kind, snapshot_interval=8)
+    _drive(eng, 8)
+    assert rules[0] == "ticks"  # the first anchor
+    assert len(rules) == eng.stats.n_snapshots
+    assert rules.count("bytes") == eng.stats.n_snapshots_by_bytes > 0
 
 
 # --------------------------------------------------- scaler + migration

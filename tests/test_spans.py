@@ -102,6 +102,9 @@ def test_spans_on_land_in_the_profiler_trace(kind, spans_on, tmp_path):
     assert all(m["step"] == 1 for m in pushes)
     assert sorted(m["job"] for n, _, _, m in found
                   if n == "ps.pull") == sorted(TREES)
+    # The first anchor of each lane is taken by the tick rule.
+    assert all(m["by"] == "ticks" for n, _, _, m in found
+               if n == "ps.snapshot")
     (tick,) = [s for s in found if s[0] == "ps.tick"]
     assert tick[3]["tick"] == 0 and tick[3]["pieces"] >= len(TREES)
     launches = [s for s in found if s[0] == "ps.launch"]
