@@ -12,8 +12,11 @@ run.
 With ``repro.ps.spans`` on, the engine also records host spans named
 ``ps.*`` (``ps.push``, ``ps.pull``, ``ps.tick``, ``ps.lane_tick``,
 ``ps.compile``, ``ps.snapshot``, ``ps.launch``, ``ps.rollback``,
-``ps.fallback``).  ``chipbench/run.py`` neither turns them on nor keeps
-them, so this module's own command does both for one cell's window::
+``ps.fallback``).  ``chipbench/run.py --trace 1`` turns them on, and
+``chipbench/trace.py`` keeps them with the device's idle time by the
+innermost one; the readers ``engine.tick_self_ms.saturate`` and
+``device.idle_engine_pct.saturate`` take them from there.  This module's
+own command shows more of one cell's window, with the spans on or off::
 
     python3 chipbench/engine_trace.py --workload <cell> --seed <n> \
         --seconds <s> [--spans 0|1]
@@ -22,8 +25,8 @@ It prints one JSON object: the engine's host time by span (self time is a
 span's time less that of the spans inside it), the device's idle seconds
 by the innermost ``ps.*`` span open over them (``outside`` where none is)
 and by the device program executing over them, the device time by
-program, and the readings those give, beside the cell's end-to-end rate.  It makes
-no correctness check: that is ``chipbench/run.py``'s.
+program, and the readings those give, beside the cell's end-to-end rate.
+It makes no correctness check: that is ``chipbench/run.py``'s.
 """
 
 from __future__ import annotations
@@ -34,7 +37,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import collections  # noqa: E402
-import glob  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -48,8 +50,6 @@ ROOT = os.path.dirname(HERE)
 PUSH_PROGRAM = "jit_push_pack"
 PULL_PROGRAM = "jit_pull_gather"
 COPY_PROGRAM = "jit_state_copy"
-SPAN_PREFIX = "ps."
-OUTSIDE = "outside"
 
 
 # ------------------------------------------------------- program readers
@@ -94,22 +94,6 @@ def device_by_program(modules) -> Dict[str, Tuple[int, float]]:
 
 
 # -------------------------------------------------------- span reductions
-def load_spans(trace_dir: str):
-    """The engine's ``ps.*`` host spans in the one ``.xplane.pb`` under
-    ``trace_dir``, as :class:`chipbench.trace.Event`."""
-    import jax
-
-    from chipbench.trace import Event
-
-    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                        recursive=True)
-    data = jax.profiler.ProfileData.from_file(path)
-    return [Event(e.name, float(e.start_ns), float(e.duration_ns))
-            for plane in data.planes if plane.name == "/host:CPU"
-            for line in plane.lines for e in line.events
-            if e.name.startswith(SPAN_PREFIX)]
-
-
 def _parents(spans) -> Dict[int, object]:
     """``{id(span): the span it lies directly inside, or None}``; the
     engine's spans nest, as spans of one thread do."""
@@ -162,83 +146,15 @@ def fallback_ms_per_tick(spans) -> Optional[float]:
     return sum(e.dur_ns for e in falls + failed) / n_ticks / 1e6
 
 
-def innermost(spans) -> List[Tuple[float, float, str]]:
-    """Time cut into ``(start, end, name)`` pieces by the innermost span
-    open over it; time under no span is left out."""
-    out, stack, t = [], [], float("-inf")
-
-    def advance(to):
-        nonlocal t
-        while stack and stack[-1].end_ns <= to:
-            top = stack.pop()
-            if top.end_ns > t:
-                out.append((t, top.end_ns, top.name))
-                t = top.end_ns
-        if stack and to > t:
-            out.append((t, to, stack[-1].name))
-        t = max(t, to)
-
-    for e in sorted(spans, key=lambda e: (e.start_ns, -e.dur_ns)):
-        advance(e.start_ns)
-        stack.append(e)
-    advance(float("inf"))
-    return [(a, b, n) for a, b, n in out if b > a]
-
-
-def idle_by_span(idle, spans) -> Dict[str, float]:
-    """Seconds of the idle stretches ``idle`` (sorted, disjoint ``(start,
-    end)`` in ns) by the innermost span open over them, ``outside``
-    where none is."""
-    pieces = innermost(spans)
-    out = collections.Counter()
-    j = 0
-    for a, b in idle:
-        under = 0.0
-        while j < len(pieces) and pieces[j][1] <= a:
-            j += 1
-        k = j
-        while k < len(pieces) and pieces[k][0] < b:
-            s, e, name = pieces[k]
-            ov = min(b, e) - max(a, s)
-            if ov > 0:
-                out[name] += ov / 1e9
-                under += ov
-            k += 1
-        if b - a > under:
-            out[OUTSIDE] += (b - a - under) / 1e9
-    return dict(out)
-
-
-def device_idle(device, lo: float, hi: float):
-    """The device's idle stretches inside [lo, hi], as ``trace.summarize``
-    finds them (no operation or DMA running)."""
-    from chipbench import trace
-
-    busy = trace.union(
-        (e.start_ns, e.end_ns)
-        for line in (trace.OPS_LINE, trace.ASYNC_OPS_LINE)
-        for e in trace._clip(device.get(line, []), lo, hi))
-    return trace.gaps(busy, lo, hi)
-
-
 def idle_by_program(idle, device) -> Dict[str, float]:
     """Seconds of the idle stretches ``idle`` by the device program
     executing over them (the ``XLA Modules`` line), ``outside`` where
     none is: idle inside a program is the gaps between its operations."""
-    from chipbench.trace import MODULES_LINE, Event, program_name
+    from chipbench import trace
 
-    return idle_by_span(idle, [Event(program_name(e.name), e.start_ns,
-                                     e.dur_ns)
-                               for e in device.get(MODULES_LINE, [])])
-
-
-def engine_idle_pct(by_span: Dict[str, float], window_s: float):
-    """Share (%) of the window in which the device was idle under an open
-    ``ps.*`` span."""
-    if window_s <= 0:
-        return None
-    return 100.0 * sum(s for n, s in by_span.items()
-                       if n != OUTSIDE) / window_s
+    return trace.idle_by_span(idle, [
+        trace.Event(trace.program_name(e.name), e.start_ns, e.dur_ns)
+        for e in device.get(trace.MODULES_LINE, [])])
 
 
 # ---------------------------------------------------------------- command
@@ -252,7 +168,6 @@ def run_spans(workload: str, seed: int, seconds: float, spans_on: bool, *,
     from chipbench import harness
     from chipbench import run as R
     from chipbench import trace as tracing
-    from repro.ps import spans
 
     cell, _, file_cfg, traffic = R.cell_spec(bench, workload)
     devs = R.device_info(int(cell["chips"]), require_tpu)
@@ -263,29 +178,30 @@ def run_spans(workload: str, seed: int, seconds: float, spans_on: bool, *,
     trace_dir = tempfile.mkdtemp(prefix="chipbench-spans-")
     try:
         h.setup()
-        spans.enable(spans_on)
-        compiles0 = h.eng.stats.n_applier_compiles
+        harness.engine_spans(spans_on)
         jax.profiler.start_trace(trace_dir)
         with h.span("window"):
             h.window(seconds, T_START if t_start is None else t_start)
         jax.profiler.stop_trace()
-        compiles = h.eng.stats.n_applier_compiles - compiles0
-        device, host = tracing.load(trace_dir)
-        run.trace = tracing.summarize(device, host)
-        ps = load_spans(trace_dir)
+        planes, host = tracing.load(trace_dir, [d.id for d in devs])
+        run.trace = tracing.summarize(planes, host)
     finally:
-        spans.enable(False)
+        harness.engine_spans(False)
         shutil.rmtree(trace_dir, ignore_errors=True)
         h.close()
     (win,) = [e for e in host if e.name == tracing.WINDOW_SPAN]
-    lo, hi = win.start_ns, win.end_ns
-    ps = [e for e in ps if lo <= e.start_ns <= hi]
-    gaps = device_idle(device, lo, hi) if device else []
-    idle = idle_by_span(gaps, ps) if device else {}
+    ps = [e for e in host if e.name.startswith(tracing.SPAN_PREFIX)
+          and win.start_ns <= e.start_ns <= win.end_ns]
+    idle = run.trace.idle_by_span if run.trace else {}
+    by_program = collections.Counter()
+    if run.trace is not None:
+        for plane in planes:
+            by_program.update(idle_by_program(
+                tracing.device_idle(plane, win.start_ns, win.end_ns), plane))
     return {
         "workload": workload, "seed": seed, "spans": bool(spans_on),
         "device": {"platform": devs[0].platform,
-                   "kind": devs[0].device_kind},
+                   "kind": devs[0].device_kind, "count": len(devs)},
         "updates_per_s": R.reader("updates_per_s")(run),
         "sync_ms_p95": R.reader("sync_ms_p95")(run),
         "engine.tick_host_ms": R.reader("engine.tick_host_ms.saturate")(run),
@@ -295,12 +211,14 @@ def run_spans(workload: str, seed: int, seconds: float, spans_on: bool, *,
         "pull.device_ms": mean_device_ms(run, PULL_PROGRAM),
         "push.device_ms": mean_device_ms(run, PUSH_PROGRAM),
         "device.idle_pct": run.trace.idle_pct if run.trace else None,
-        "device.idle_engine_pct": (engine_idle_pct(idle, (hi - lo) / 1e9)
-                                   if device else None),
-        "engine.applier_compiles": compiles,
+        "device.idle_engine_pct": (
+            tracing.engine_idle_pct(idle, run.trace.window_s)
+            if run.trace else None),
+        "engine.applier_compiles": R.reader(
+            "engine.applier_compiles.cadence")(run),
         "idle_by_span": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
         "idle_by_host": run.trace.idle_by_host if run.trace else {},
-        "idle_by_program": idle_by_program(gaps, device) if device else {},
+        "idle_by_program": dict(by_program),
         "device_by_program": (device_by_program(run.trace.modules)
                               if run.trace else {}),
         "host_by_span": host_by_span(ps),

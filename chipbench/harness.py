@@ -17,6 +17,7 @@ parameters are ready on the device.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import itertools
 import math
@@ -62,6 +63,16 @@ def validate(cfg: dict):
                          "gradient: worker_pushes must be 1")
     if cfg["grad_trees"] not in (1, 2):
         raise ValueError("grad_trees must be 1 or 2")
+
+
+def engine_spans(on: bool) -> None:
+    """Turn the engine's own ``ps.*`` spans on or off, where the program
+    under test has them."""
+    try:
+        from repro.ps import spans
+    except ImportError:
+        return
+    spans.enable(on)
 
 
 def _loss(params, batch):
@@ -121,7 +132,9 @@ class Run:
     compiled: List[tuple] = field(default_factory=list)  # (name, seconds)
     cache_misses: int = 0  # of those, compiled anew
     counters: Dict[str, Any] = field(default_factory=dict)
-    memory_peak_bytes: Optional[int] = None
+    memory_peak_bytes: Optional[int] = None  # of the fullest chip
+    memory_peak_bytes_by_chip: List[Optional[int]] = field(
+        default_factory=list)
     trace: Any = None  # chipbench.trace.Summary of a traced run
     setup_phases: Dict[str, float] = field(default_factory=dict)
     failed: int = 0
@@ -348,6 +361,7 @@ class Harness:
         pulled back before this returns."""
         run, clock = self.run, time.perf_counter
         run.seconds = float(seconds)
+        stats0 = self._int_stats()
         self._compiles[:] = [0, 0]
         self._lowered.clear()
         run.t0 = t0 = clock()
@@ -449,13 +463,17 @@ class Harness:
             n_fleet_fallbacks=stats.n_fleet_fallbacks,
             n_rollbacks=stats.n_rollbacks, n_quarantines=stats.n_quarantines,
             n_snapshots=stats.n_snapshots, n_replans=self.rt.n_replans,
-            # executables of the per-lane appliers, which the trace names
-            # as the fleet applier is named (jit_apply)
-            lane_executables=sum(len(a._exes) for lane in
-                                 self.eng._lanes.values()
-                                 for a in lane.appliers.values()),
             n_shards=self.rt.n_shards,
-            lanes=int(self.rt.splan.total_len) if self.rt.splan else 0)
+            lanes=int(self.rt.splan.total_len) if self.rt.splan else 0,
+            window={k: v - stats0[k] for k, v in self._int_stats().items()})
+
+    def _int_stats(self) -> Dict[str, int]:
+        """Every integer counter of the engine's ``TickStats``, so that a
+        counter the engine gains reaches the readers with no edit here."""
+        stats = self.eng.stats
+        values = {f.name: getattr(stats, f.name)
+                  for f in dataclasses.fields(stats)}
+        return {k: v for k, v in values.items() if isinstance(v, int)}
 
     # ---------------------------------------------------------------- check
     def release(self):
@@ -481,7 +499,12 @@ class Harness:
         (``gap``), and of its pull after update ``check_step``
         (``gap_at_k``; a residency that ended before that step gives its
         last pull).  With ``control`` (a dtype) the reference computed in
-        that precision takes the pulls' place."""
+        that precision takes the pulls' place.
+
+        Each tensor is replayed alone, on the chip its trees were made on,
+        with its pulled leaf moved there, and the maxima are reduced on
+        the host: the replay holds the moments of one tensor."""
+        import jax
         import jax.numpy as jnp
 
         gaps = {}
@@ -490,14 +513,21 @@ class Harness:
                 reference.tree_key(self.seed, t.index, 2 + arrival))
             grads = self._grads(t)
             k = self.check_step if at_k is not None else steps
-            for name, n, got in (("gap_at_k", k, at_k), ("gap", steps, pulled)):
-                ref = reference.replay(self.opt, init, grads, n)
-                if control is not None:
-                    got = reference.replay(self.opt, init, grads, n,
-                                           jnp.dtype(control))
-                elif got is None:
-                    got = pulled
-                gaps[f"{name}.{t.name}"] = (reference.gap(got, ref, init), n)
-                del ref, got
-            del grads, init
+            checks = (("gap_at_k", k, pulled if at_k is None else at_k),
+                      ("gap", steps, pulled))
+            parts = {name: [] for name, _, _ in checks}
+            for tensor, p in init.items():
+                gs = tuple(g[tensor] for g in grads)
+                for name, n, got in checks:
+                    ref = reference.replay(self.opt, p, gs, n)
+                    if control is not None:
+                        mine = reference.replay(self.opt, p, gs, n,
+                                                jnp.dtype(control))
+                    else:
+                        mine = jax.device_put(got[tensor], p.device)
+                    parts[name].append(reference.gap_parts(mine, ref, p))
+                    del ref, mine
+            del init, grads
+            for name, n, _ in checks:
+                gaps[f"{name}.{t.name}"] = (reference.gap_of(parts[name]), n)
         return gaps

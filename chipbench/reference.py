@@ -1,9 +1,13 @@
-"""The plain reference: a per-tenant Adam in ``jax.numpy`` on unpacked trees.
+"""The plain reference: a per-tenant Adam in ``jax.numpy``, one tensor at a
+time.
 
 It imports nothing of the system under test.  It replays a tenant's whole
 run from the seed -- the initial parameters and the two gradient trees
-the tenant pushed in turn -- one tenant at a time on the device, after
-the measured window has closed and the service's state is freed.
+the tenant pushed in turn -- tensor by tensor, on the chip where the
+tenant's trees were made, after the measured window has closed and the
+service's state is freed.  Adam is elementwise, so a tensor replayed
+alone gives what the whole tree gives; the replay then holds the moments
+of one tensor, not of the tenant.
 
 ``dtype=float32`` is the reference; ``dtype=bfloat16`` is the control,
 the same arithmetic one precision below the configuration's.  A tenant
@@ -11,6 +15,8 @@ with one gradient tree pushes it every step; with two, in turn.
 """
 
 from __future__ import annotations
+
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,22 +96,31 @@ _replay = jax.jit(_adam_steps,
 
 
 @jax.jit
-def _gap(pulled, ref, init):
-    """(max |pulled - ref|, max |ref - init|) over every tensor of one
-    tenant."""
-    err = jnp.max(jnp.stack([jnp.max(jnp.abs(pulled[k].reshape(-1) - ref[k]))
-                             for k in ref]))
-    moved = jnp.max(jnp.stack([jnp.max(jnp.abs(ref[k] - init[k]))
-                               for k in ref]))
-    return err, moved
+def _gap_parts(pulled, ref, init):
+    """(max |pulled - ref|, max |ref - init|) over one tensor."""
+    return (jnp.max(jnp.abs(pulled.reshape(-1) - ref)),
+            jnp.max(jnp.abs(ref - init)))
 
 
 def replay(opt, init, grads, steps, dtype=jnp.float32):
-    """The tenant's parameters after ``steps`` updates, replayed."""
+    """The parameters after ``steps`` updates, replayed: of one tensor, or
+    of a whole tree, which gives the same numbers tensor by tensor."""
     g0, g1 = grads[0], grads[-1]
     return _replay(init, g0, g1, jnp.int32(steps),
                    lr=float(opt["lr"]), b1=float(opt["b1"]),
                    b2=float(opt["b2"]), eps=float(opt["eps"]), dtype=dtype)
+
+
+def gap_parts(pulled, ref, init) -> Tuple[float, float]:
+    """The two maxima of :func:`gap` over one tensor, on the host."""
+    err, moved = jax.device_get(_gap_parts(pulled, ref, init))
+    return float(err), float(moved)
+
+
+def gap_of(parts) -> float:
+    """:func:`gap` from every tensor's :func:`gap_parts`."""
+    parts = list(parts)
+    return max(e for e, _ in parts) / max(m for _, m in parts)
 
 
 def gap(pulled, ref, init) -> float:
@@ -115,5 +130,4 @@ def gap(pulled, ref, init) -> float:
     same share of each step, so the number stays put as a faster service
     fits more steps into the window; a lost, doubled or altered update
     moves it by a large share of one step."""
-    err, moved = jax.device_get(_gap(pulled, ref, init))
-    return float(err) / float(moved)
+    return gap_of(gap_parts(pulled[k], ref[k], init[k]) for k in ref)

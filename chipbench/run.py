@@ -8,13 +8,15 @@ from the root of a checkout.  ``BENCHMARK.json`` names the cell's
 configuration (``chipbench/configs/<name>.json``) and traffic
 (``chipbench/traffic/<name>.json``); each metric is read by
 ``chipbench/metrics/<metric>.py``.  The run makes every tenant's
-parameters and gradients on the device from ``--seed``, warms up every
-program the window uses, measures for ``--seconds``, replays each tenant
-on the plain reference, and prints one JSON object as the last line of
-standard output.  With ``--trace 1`` the window runs under the profiler
-and the per-layer metrics are printed; with ``--trace 0`` the end-to-end
-ones.  Without a TPU, or with fewer chips than the cell asks for, it
-exits non-zero and prints no result.
+parameters and gradients on the cell's first chip from ``--seed``, warms up
+every program the window uses, measures for ``--seconds``, replays each
+tenant on the plain reference tensor by tensor, and prints one JSON
+object as the last line of standard output.  With ``--trace 1`` the
+window runs under the profiler with the engine's own spans on, and the
+per-layer metrics are printed; with ``--trace 0`` the end-to-end ones.
+Memory and trace are read on every chip of the cell.  Without a TPU, or
+with fewer chips than the cell asks for, it exits non-zero and prints no
+result.
 """
 
 from __future__ import annotations
@@ -93,6 +95,15 @@ def device_info(chips: int, require_tpu: bool = True):
     return devs[:chips]
 
 
+def memory_peaks(devs):
+    """(the fullest chip's ``peak_bytes_in_use``, each chip's); None
+    where the backend reports none."""
+    by_chip = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+               for d in devs]
+    known = [b for b in by_chip if b is not None]
+    return (max(known) if known else None), by_chip
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              bench: dict, require_tpu: bool = True, cfg: dict = None,
              control: str = None, t_start: float = None, log=print):
@@ -115,19 +126,26 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t_start = T_START if t_start is None else t_start
     try:
         h.setup()
+        _, open_peaks = memory_peaks(devs)
         trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         try:
             if trace:
+                harness.engine_spans(True)
                 jax.profiler.start_trace(trace_dir)
             with h.span("window"):
                 h.window(seconds, t_start)
             if trace:
                 jax.profiler.stop_trace()
-                run.trace = tracing.summarize(*tracing.load(trace_dir))
+                planes, host = tracing.load(trace_dir, [d.id for d in devs])
+                log(f"trace: {sum(map(bool, planes))} of {len(planes)} "
+                    f"chips' planes found")
+                run.trace = tracing.summarize(planes, host)
         finally:
+            if trace:
+                harness.engine_spans(False)
             shutil.rmtree(trace_dir, ignore_errors=True)
-        stats = dev.memory_stats() or {}
-        run.memory_peak_bytes = stats.get("peak_bytes_in_use")
+        run.memory_peak_bytes, run.memory_peak_bytes_by_chip = \
+            memory_peaks(devs)
         kept = h.release()
         t_ref = time.perf_counter()
         gaps = h.check(kept)
@@ -166,13 +184,21 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         host[name] = host.get(name, 0.0) + b - a
     log("host seconds by span in the window: " + json.dumps(
         {k: round(v, 3) for k, v in sorted(host.items())}))
-    log(f"memory: peak_bytes_in_use {run.memory_peak_bytes} of "
-        f"bytes_limit {stats.get('bytes_limit')}")
+    if run.trace is not None:
+        log("device busy seconds by chip: " + json.dumps(
+            [round(c.busy_s, 3) for c in run.trace.chips]))
+        log("device idle chip-seconds by innermost ps.* span: " + json.dumps(
+            {k: round(v, 3) for k, v in sorted(
+                run.trace.idle_by_span.items(), key=lambda kv: -kv[1])}))
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    log(f"memory: peak_bytes_in_use by chip {run.memory_peak_bytes_by_chip} "
+        f"({open_peaks} when the window opened) of bytes_limit {limit}")
     for name, (g, steps) in sorted(gaps.items()):
         log(f"{name} {g:.6e} limit {checks[name]['limit']:.1e} "
             f"({steps} steps)")
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devs), "memory_peak_bytes": run.memory_peak_bytes}
+              "count": len(devs), "memory_peak_bytes": run.memory_peak_bytes,
+              "memory_peak_bytes_by_chip": run.memory_peak_bytes_by_chip}
     out = {"correct": correct, "attempted": len(run.iters),
            "failed": run.failed, "metrics": metrics, "device": device}
     if trace and run.trace is not None:

@@ -50,8 +50,13 @@ def synthetic():
     return device, host
 
 
+def summary(device, host):
+    """The summary of one chip's plane."""
+    return trace.summarize([device], host)
+
+
 def test_summary_busy_idle_and_host_attribution():
-    s = trace.summarize(*synthetic())
+    s = summary(*synthetic())
     assert s.window_s == pytest.approx(0.1)
     # Busy: the union 12-33, 35-40 and 62-80 ms.
     assert s.busy_s == pytest.approx(0.044)
@@ -65,10 +70,10 @@ def test_summary_busy_idle_and_host_attribution():
 
 
 def test_summary_attributes_ops_to_program_executions():
-    s = trace.summarize(*synthetic())
+    s = summary(*synthetic())
     runs = s.executions(FLEET_PROGRAM)
     assert len(runs) == 2  # the third lies outside the window
-    inside = s.ops_within(runs)
+    inside = s.chips[0].ops_within(runs)
     assert sorted(trace.op_name(e.name) for e in inside) == sorted(
         [AGG_ADAM_KERNEL + ".1", AGG_ADAM_KERNEL, "copy.1", "copy.1"])
     assert dict(s.top_ops())[AGG_ADAM_KERNEL] == pytest.approx(0.012)
@@ -84,8 +89,47 @@ def test_union_and_gaps():
 
 def test_summary_without_window_or_ops_is_none():
     device, host = synthetic()
-    assert trace.summarize(device, host[1:]) is None
-    assert trace.summarize({}, host) is None
+    assert summary(device, host[1:]) is None
+    assert summary({}, host) is None
+    assert trace.summarize([{}, {}], host) is None
+
+
+def test_planes_of_several_chips_sum_to_chip_seconds():
+    """Two chips that ran alike read twice the chip-seconds and the same
+    idle share; a third chip that ran nothing adds a whole idle window."""
+    device, host = synthetic()
+    one = summary(device, host)
+    two = trace.summarize([device, device], host)
+    assert two.window_s == pytest.approx(0.2)
+    assert two.busy_s == pytest.approx(2 * one.busy_s)
+    assert two.idle_pct == pytest.approx(one.idle_pct)
+    assert two.idle_by_host == pytest.approx(
+        {k: 2 * v for k, v in one.idle_by_host.items()})
+    assert [len(c.executions(FLEET_PROGRAM)) for c in two.chips] == [2, 2]
+    assert len(two.executions(FLEET_PROGRAM)) == 4
+    assert dict(two.top_ops()) == pytest.approx(
+        {k: 2 * v for k, v in dict(one.top_ops()).items()})
+    three = trace.summarize([device, device, {}], host)
+    assert three.window_s == pytest.approx(0.3)
+    assert three.busy_s == pytest.approx(two.busy_s)
+    # the idle chip's whole window, put down to the span overlapping most
+    assert three.idle_by_host["client.wait"] == pytest.approx(
+        two.idle_by_host["client.wait"] + 0.1)
+
+
+def test_memory_peaks_reduce_to_the_fullest_chip():
+    class Chip:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    chips = [Chip({"peak_bytes_in_use": 7, "bytes_limit": 16}),
+             Chip({"peak_bytes_in_use": 11}), Chip(None), Chip({})]
+    assert R.memory_peaks(chips) == (11, [7, 11, None, None])
+    assert R.memory_peaks(chips[:1]) == (7, [7])
+    assert R.memory_peaks([Chip(None)]) == (None, [None])
 
 
 def test_required_bytes_and_roofline_share():
@@ -109,7 +153,7 @@ def test_unknown_device_kind_raises():
 def _saturate_run(n_ticks):
     run = Run(cell="c", config={}, traffic={}, seconds=0.1,
               device_kind="TPU v5 lite")
-    run.trace = trace.summarize(*synthetic())
+    run.trace = summary(*synthetic())
     # Each tick applied 100M real parameters: 2.8 GB required.
     run.ticks = [(0, 0, 100_000_000)] * n_ticks
     return run
@@ -124,6 +168,41 @@ def test_fleet_and_kernel_roofline_from_required_bytes():
     # Ticks that the trace cannot match one to one give no reading.
     assert R.reader("fleet_tick_roofline")(_saturate_run(3)) is None
     assert R.reader("device.idle_pct.saturate")(run) == pytest.approx(56.0)
+
+
+def test_rooflines_take_one_fleet_execution_per_chip_per_tick():
+    """The bytes the ticks need over the device time summed over the
+    chips that ran the fleet program: the same share however the work is
+    spread; a chip that ran it once too often gives no reading."""
+    device, host = synthetic()
+    run = _saturate_run(2)
+    run.trace = trace.summarize([device, device, {}], host)
+    assert R.reader("fleet_tick_roofline")(run) == pytest.approx(
+        100 * 5.6e9 / 819e9 / 0.080)
+    assert R.reader("agg_adam_roofline")(run) == pytest.approx(
+        100 * 5.6e9 / 819e9 / 0.048)
+    extra = dict(device)
+    extra[trace.MODULES_LINE] = device[trace.MODULES_LINE] + [
+        ev(FLEET_PROGRAM + "(7)", 85, 10)]
+    run.trace = trace.summarize([device, extra], host)
+    assert R.reader("fleet_tick_roofline")(run) is None
+    assert R.reader("agg_adam_roofline")(run) is None
+
+
+def test_rooflines_gate_on_fallbacks_in_the_window_only():
+    """A fallback during warm-up leaves the window's rooflines readable;
+    one inside the window gives no reading."""
+    run = _saturate_run(2)
+    run.counters = {"n_fleet_fallbacks": 1,
+                    "window": {"n_fleet_fallbacks": 0}}
+    assert R.reader("fleet_tick_roofline")(run) == pytest.approx(
+        100 * 5.6e9 / 819e9 / 0.040)
+    assert R.reader("agg_adam_roofline")(run) == pytest.approx(
+        100 * 5.6e9 / 819e9 / 0.024)
+    run.counters = {"n_fleet_fallbacks": 2,
+                    "window": {"n_fleet_fallbacks": 1}}
+    assert R.reader("fleet_tick_roofline")(run) is None
+    assert R.reader("agg_adam_roofline")(run) is None
 
 
 def test_host_clock_readers():

@@ -1,6 +1,7 @@
 """CPU tests of the readings taken from the tick engine's own names: its
 programs (``jit_push_pack``, ``jit_pull_gather``, ``jit_state_copy``) and
-its ``ps.*`` host spans (``chipbench/engine_trace.py``)."""
+its ``ps.*`` host spans (``chipbench/engine_trace.py``, and their
+reduction in ``chipbench/trace.py``)."""
 
 import os
 import sys
@@ -19,7 +20,7 @@ from chipbench.harness import Run  # noqa: E402
 from chipbench.readers import FLEET_PROGRAM  # noqa: E402
 from test_chipbench_arith import ev, synthetic  # noqa: E402
 
-OUT = ET.OUTSIDE
+OUT = trace.OUTSIDE
 READERS = ("pull.device_ms.saturate", "push.device_ms.saturate",
            "engine.state_copy_ms.saturate")
 
@@ -42,7 +43,7 @@ def engine_spans():
 def _run(device, n_ticks=2):
     run = Run(cell="c", config={}, traffic={}, seconds=0.1, t0=0.0,
               t_close=1.0, device_kind="TPU v5 lite")
-    run.trace = trace.summarize(device, synthetic()[1])
+    run.trace = trace.summarize([device], synthetic()[1])
     run.spans = ([("engine.tick", 0.1 * i, 0.1 * i + 0.01)
                   for i in range(n_ticks)]
                  + [("engine.tick", 5.0, 5.1)])  # after the window
@@ -125,15 +126,15 @@ def test_idle_goes_to_the_innermost_span_or_outside():
     ms = 1e6
     idle = [(0, 12 * ms), (33 * ms, 35 * ms), (40 * ms, 62 * ms),
             (80 * ms, 100 * ms), (120 * ms, 130 * ms)]
-    got = ET.idle_by_span(idle, engine_spans())
+    got = trace.idle_by_span(idle, engine_spans())
     assert got == pytest.approx({
         "ps.push": 0.006, "ps.compile": 0.003, OUT: 0.021,
         "ps.tick": 0.003, "ps.snapshot": 0.001,
         "ps.launch": 0.006, "ps.lane_tick": 0.002, "ps.fallback": 0.002,
         "ps.pull": 0.022})
     assert sum(got.values()) == pytest.approx(0.066)
-    assert ET.engine_idle_pct(got, 0.1) == pytest.approx(45.0)
-    assert ET.idle_by_span(idle, []) == pytest.approx({OUT: 0.066})
+    assert trace.engine_idle_pct(got, 0.1) == pytest.approx(45.0)
+    assert trace.idle_by_span(idle, []) == pytest.approx({OUT: 0.066})
 
 
 def test_idle_by_the_program_executing_over_it():
@@ -149,19 +150,52 @@ def test_idle_by_the_program_executing_over_it():
 
 def test_device_idle_matches_the_summary():
     device, host = synthetic()
-    s = trace.summarize(device, host)
-    idle = ET.device_idle(device, 0, 100e6)
+    s = trace.summarize([device], host)
+    idle = trace.device_idle(device, 0, 100e6)
     assert sum(b - a for a, b in idle) / 1e9 == pytest.approx(
         s.window_s - s.busy_s)
 
 
 def test_engine_spans_leave_the_host_attribution_as_it_was():
     device, host = synthetic()
-    before = trace.summarize(device, host)
-    after = trace.summarize(device, host + engine_spans())
+    before = trace.summarize([device], host)
+    after = trace.summarize([device], host + engine_spans())
     assert after.idle_by_host == before.idle_by_host == pytest.approx({
         "engine.tick": 0.012, "host.other": 0.002, "client.wait": 0.022,
         "engine.pull": 0.020})
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_engine_span_readers(chips):
+    """The readers of the engine's spans in a traced run: the tick's self
+    time, and the share of the window's chip-seconds idle under an open
+    ``ps.*`` span (45 ms of the synthetic 100 ms window on each chip)."""
+    device, host = synthetic()
+    run = _run(device)
+    run.trace = trace.summarize([device] * chips, host + engine_spans())
+    assert run.trace.idle_by_span == pytest.approx(
+        {k: chips * v for k, v in trace.idle_by_span(
+            trace.device_idle(device, 0, 100e6), engine_spans()).items()})
+    assert R.reader("engine.tick_self_ms.saturate")(run) == \
+        pytest.approx(6.0)
+    assert R.reader("device.idle_engine_pct.saturate")(run) == \
+        pytest.approx(45.0)
+    run.trace = trace.summarize([device] * chips, host)
+    assert run.trace.spans == [] and run.trace.idle_by_span == {}
+    for name in ("engine.tick_self_ms.saturate",
+                 "device.idle_engine_pct.saturate"):
+        assert R.reader(name)(run) is None, name
+        assert R.reader(name)(Run(cell="c", config={}, traffic={},
+                                  seconds=1.0)) is None, name
+
+
+def test_applier_compiles_reads_the_window_counters():
+    run = Run(cell="c", config={}, traffic={}, seconds=1.0)
+    read = R.reader("engine.applier_compiles.cadence")
+    assert read(run) is None
+    run.counters = {"n_ticks": 9, "window": {"n_applier_compiles": 3,
+                                             "n_ticks": 4}}
+    assert read(run) == 3
 
 
 @pytest.mark.parametrize("spans_on", [True, False])

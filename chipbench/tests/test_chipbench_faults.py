@@ -46,11 +46,38 @@ def test_control_fails_the_check():
     {"optimizer": {"kind": "adam", "dtype": "float32", "weight_decay": 0.01,
                    "lr": 1e-3, "b1": 0.9, "b2": 0.999, "eps": 1e-8}},
     {"worker_pushes": 2},
+    {"tree_placement": "by_tensor"},
 ])
 def test_configuration_keys_not_applied_are_refused(change):
     cfg = dict(tiny(CELL), **change)
     with pytest.raises(ValueError):
         harness.validate(cfg)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("steps", [1, 24, 25])
+def test_replay_by_tensor_equals_the_whole_tree(dtype, steps):
+    """Adam is elementwise: each tensor replayed alone gives, bit for bit,
+    what the replay of the whole tree gives, in the reference's precision
+    and in the control's."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import reference
+
+    inv = [("a", 1000), ("b", 37), ("c", 4099), ("d", 1)]
+    opt = tiny(CELL)["optimizer"]
+    params, grads = (reference.tree_maker(inv, scale)
+                     for scale in (reference.PARAM_SCALE,
+                                   reference.GRAD_SCALE))
+    init = params(reference.tree_key(SEED, 1, 2))
+    gs = tuple(grads(reference.tree_key(SEED, 1, w)) for w in (0, 1))
+    whole = reference.replay(opt, init, gs, steps, jnp.dtype(dtype))
+    for name in init:
+        one = reference.replay(opt, init[name], tuple(g[name] for g in gs),
+                               steps, jnp.dtype(dtype))
+        assert np.array_equal(one, whole[name]), name
+    assert reference.gap(whole, whole, init) == 0.0
 
 
 def test_reference_is_exact_against_itself():
